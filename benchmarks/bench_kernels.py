@@ -28,7 +28,8 @@ import numpy as np
 from benchmarks.common import row, timeit
 from benchmarks.roofline_report import roofline_fields
 from repro.core.dispatch import (_core_relax_dense, _core_relax_ell,
-                                 _core_relax_fused, CoreRelaxer, core_relax)
+                                 _core_relax_fused, CoreRelaxer, core_relax,
+                                 ell_round)
 from repro.core.labels import LabelRows, decode_ids, encode_labels, \
     encoded_nbytes
 from repro.core.query import label_intersect_mu
@@ -37,7 +38,7 @@ from repro.kernels.label_intersect.ops import (label_intersect,
                                                label_intersect_rows)
 from repro.kernels.minplus_matmul.ops import minplus_matmul
 from repro.kernels.minplus_matmul.ref import minplus_matmul_ref
-from repro.kernels.spmv_relax.ops import coo_to_ell, spmv_relax
+from repro.kernels.spmv_relax.ops import coo_to_ell
 from repro.kernels.spmv_relax.ref import spmv_relax_ref
 
 # q/l/n: label-intersect batch;  m: minplus GEMM edge;  v/qb: core-relax
@@ -181,7 +182,9 @@ def main(full: bool = False, preset: str | None = None,
     np.testing.assert_allclose(np.asarray(mp_ref), np.asarray(mp_ker),
                                rtol=1e-6)
 
-    # ---- one relaxation round at core-graph shape: ref vs kernel
+    # ---- one relaxation round at core-graph shape: ref vs the XLA
+    # gather round (the "ell_xla" stage-2 route; no Pallas form of a
+    # single round lowers, see docs/KERNELS.md)
     v, qb = p["v"], p["qb"]
     n_core, src, dst, w = _core_graph(r, v)
     e = len(src)
@@ -192,14 +195,13 @@ def main(full: bool = False, preset: str | None = None,
     us_ref, rx_ref = timeit(f, jnp.asarray(dist), ids, ws)
     krow(f"spmv_relax_ref[q{qb},v{v}]", us_ref * 1e6,
          edges_per_s=round(qb * e / us_ref / 1e6, 1))
-    g = jax.jit(lambda d, i, w_: spmv_relax(d, i, w_, backend=kernel_backend))
-    us_ker, rx_ker = timeit(g, jnp.asarray(dist), ids, ws)
-    krow(f"spmv_relax_kernel[q{qb},v{v}]", us_ker * 1e6,
-         backend=kernel_backend,
-         edges_per_s=round(qb * e / us_ker / 1e6, 1))
-    _bitwise(rx_ref, rx_ker, "spmv_relax dispatch")
+    g = jax.jit(lambda d, i, w_: ell_round(d.T, i.T, w_.T).T)
+    us_xla, rx_xla = timeit(g, jnp.asarray(dist), ids, ws)
+    krow(f"spmv_relax_xla[q{qb},v{v}]", us_xla * 1e6, backend="xla",
+         edges_per_s=round(qb * e / us_xla / 1e6, 1))
+    _bitwise(rx_ref, rx_xla, "spmv_relax xla round")
 
-    # ---- whole core search, fused kernel vs per-round launch loop:
+    # ---- whole core search, fused kernel vs the XLA per-round loop:
     # the same graph relaxed to its fixed point. Distances, the μ
     # answer, and the round count must agree bitwise (max over
     # per-block in-kernel exits == loop rounds); both checked against
@@ -213,8 +215,7 @@ def main(full: bool = False, preset: str | None = None,
         return _core_relax_fused(a, b, ids, ws, mu, n_core, MAXR, interp, 8)
 
     def loop_call(a, b):
-        return _core_relax_ell(a, b, ids, ws, mu, n_core, MAXR, interp,
-                               8, 128)
+        return _core_relax_ell(a, b, ids, ws, mu, n_core, MAXR)
 
     us_fu, (ans_fu, ds_fu, dt_fu, r_fu) = timeit(fused_call, seed_s, seed_t)
     us_lp, (ans_lp, ds_lp, dt_lp, r_lp) = timeit(loop_call, seed_s, seed_t)
@@ -229,8 +230,8 @@ def main(full: bool = False, preset: str | None = None,
     assert rounds == int(r_ref), "fused/reference round-count parity failed"
     for kr, rr in ((ans_fu, ans_ref), (ds_fu, ds_ref), (dt_fu, dt_ref)):
         _bitwise(rr, kr, "fused-vs-reference core-relax")
-    krow(f"relax_loop_kernel[q{qb},v{v},r{rounds}]", us_lp * 1e6,
-         backend=kernel_backend, rounds=rounds)
+    krow(f"relax_loop_xla[q{qb},v{v},r{rounds}]", us_lp * 1e6,
+         backend="xla", rounds=rounds)
     krow(f"fused_relax_kernel[q{qb},v{v},r{rounds}]", us_fu * 1e6,
          backend=kernel_backend, rounds=rounds,
          speedup_vs_loop=round(us_lp / us_fu, 2))

@@ -6,7 +6,8 @@ Each graph is measured through BOTH dispatch paths side by side:
 
   * ``reference`` — the jnp searchsorted merge + COO scatter relaxation,
     one dense [Q, n_core+1] frontier per direction for the whole batch.
-  * ``kernel``    — the Pallas label-intersect + ELL spmv_relax kernels,
+  * ``kernel``    — the Pallas label-intersect kernel + the stage-2
+    route ``CoreRelaxer.mode`` picks (fused/dense kernel or XLA round),
     query-chunked so the stage-2 frontier is [chunk, n_core+1] and the
     full batch never materializes a dense [Q, n_core+1] matrix in one
     launch. On TPU this is the compiled production path over the full
@@ -22,7 +23,7 @@ Two extra row families on the first graph gate this PR's optimizations:
 
   * ``relax_fused`` vs ``relax_loop`` — the same batch-64 query run
     with the stage-2 dispatcher pinned to the fused all-rounds kernel
-    vs the legacy one-launch-per-round loop; answers and round counts
+    vs the XLA per-round gather loop; answers and round counts
     asserted bitwise-equal before the speedup is reported.
   * ``compressed`` — a ``label_dtype="auto"`` index (delta16 ids +
     int32 distances, decode fused into the kernels) Dijkstra-verified
@@ -53,29 +54,29 @@ def _verify_exact(name, got, want):
 
 
 def _fused_vs_loop(name, eng, kb, s, t, want):
-    """Batch-64 query through the fused stage-2 kernel vs the per-round
-    launch loop (same engine, relaxer pinned per run): bitwise-equal
+    """Batch-64 query through the fused stage-2 kernel vs the XLA
+    per-round loop (same engine, relaxer pinned per run): bitwise-equal
     answers and rounds asserted, speedup reported."""
     qf = 64
     sj, tj = jnp.asarray(s[:qf]), jnp.asarray(t[:qf])
     fused_rx = CoreRelaxer(eng.ce_src, eng.ce_dst, eng.ce_w, eng.n_core,
                            dense_threshold=2.0)
-    if fused_rx.mode != "fused":
-        if kb == "pallas":
-            # real VMEM: the graph's ELL width doesn't fit the fused
-            # budget — the ell_loop fallback IS the production route
-            # here, so there is no fused row to measure.
-            print(f"# {name}: fused working set over VMEM budget, "
-                  "skipping fused-vs-loop row")
-            return
+    if fused_rx.mode != "fused" and kb != "pallas":
         # interpret mode has no real VMEM; widen the budget so the
         # comparison still runs on wide-ELL graphs
         fused_rx = CoreRelaxer(eng.ce_src, eng.ce_dst, eng.ce_w,
                                eng.n_core, dense_threshold=2.0,
                                vmem_budget=1 << 62)
+    if fused_rx.mode != "fused":
+        # the core is above FUSED_MAX_V (or, compiled, its ELL too wide
+        # for VMEM) — the XLA round IS the production route here, so
+        # there is no fused row to measure.
+        print(f"# {name}: core outside the fused kernel's range, "
+              "skipping fused-vs-loop row")
+        return
     loop_rx = CoreRelaxer(eng.ce_src, eng.ce_dst, eng.ce_w, eng.n_core,
                           fused=False, dense_threshold=2.0)
-    assert fused_rx.mode == "fused" and loop_rx.mode == "ell_loop"
+    assert loop_rx.mode == "ell_xla"
     orig = eng.relaxer
     out = {}
     try:

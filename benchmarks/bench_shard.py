@@ -3,11 +3,14 @@
 unsharded engine (full path *and* μ lane). Results accumulate in
 ``BENCH_shard.json``.
 
-Shard counts > the real device count need simulated devices, and
-``XLA_FLAGS`` must be set before jax initializes — so when the process
-has too few devices this suite re-execs itself in a subprocess with
-``--xla_force_host_platform_device_count=<max shards>`` and streams the
-child's CSV rows through (the child writes the JSON).
+On real chips the suite runs in this one process, over the shard
+counts the host's devices allow: a chip belongs to one process, so a
+parent that has touched JAX would leave a child without it. On the CPU
+(``JAX_PLATFORMS=cpu``) it simulates devices instead: ``XLA_FLAGS``
+must be set before JAX initializes, so the suite re-execs itself with
+``--xla_force_host_platform_device_count=<max shards>`` — deciding from
+the environment alone, before this module touches JAX — and streams
+the child's CSV rows through (the child writes the JSON).
 
   PYTHONPATH=src python -m benchmarks.bench_shard [--full] [--out DIR]
 """
@@ -36,6 +39,7 @@ def _reexec_with_devices(full: bool, n_dev: int) -> None:
                         + f" --xla_force_host_platform_device_count={n_dev}"
                         ).strip()
     env["_BENCH_SHARD_CHILD"] = "1"
+    env["JAX_PLATFORMS"] = "cpu"
     src = str(Path(__file__).resolve().parents[1] / "src")
     env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
     cmd = [sys.executable, "-m", "benchmarks.bench_shard",
@@ -50,11 +54,8 @@ def _reexec_with_devices(full: bool, n_dev: int) -> None:
 
 
 def main(full: bool = False) -> None:
-    import jax
-    if len(jax.devices()) < max(SHARD_COUNTS):
-        if os.environ.get("_BENCH_SHARD_CHILD"):
-            raise RuntimeError(
-                "forced device count did not take effect in the subprocess")
+    if (os.environ.get("JAX_PLATFORMS") == "cpu"
+            and not os.environ.get("_BENCH_SHARD_CHILD")):
         _reexec_with_devices(full, max(SHARD_COUNTS))
         return
     _run(full)
@@ -75,8 +76,12 @@ def _run(full: bool) -> None:
     idx = ISLabelIndex.build(n, src, dst, w, IndexConfig(l_cap=512))
     rng = np.random.default_rng(0)
 
+    shard_counts = [p for p in SHARD_COUNTS if p <= len(jax.devices())]
+    if os.environ.get("_BENCH_SHARD_CHILD"):
+        assert shard_counts == list(SHARD_COUNTS), \
+            "forced device count did not take effect in the subprocess"
     results, gate_passed = [], True
-    for shards in SHARD_COUNTS:
+    for shards in shard_counts:
         sidx = ShardedIndex.from_index(idx, shards, strategy="level")
         for batch in _batch_sizes(full):
             s = rng.integers(0, n, batch).astype(np.int32)

@@ -33,7 +33,8 @@ Traffic models (compulsory bytes, fp32/int32 = 4 B):
   ONCE (``8·q·v + 8·v·d``) while all ``r`` rounds' flops
   (``2·q·v·d·r``) run out of VMEM — intensity scales with rounds,
   which is the point of the fusion. ``relax_loop[...]`` is the same
-  search through per-round launches: ``r×`` the bytes at equal flops.
+  search one round per loop step (the XLA ``"ell_xla"`` route): ``r×``
+  the bytes at equal flops.
 * ``minplus[m^3]``: dense tropical GEMM, ``4·3·m²`` B compulsory,
   ``2·m³`` flops. ``dense_relax[q, v, r]``: r tropical GEMM rounds of
   the [q, v]×[v, v] frontier product (q = both frontiers stacked).
@@ -77,7 +78,7 @@ def fused_relax_model(q: int, v: int, rounds: int,
 
 def relax_loop_model(q: int, v: int, rounds: int,
                      d_width: int = ELL_D_WIDTH) -> tuple[float, float]:
-    """The same search as per-round launches: r× the HBM traffic."""
+    """The same search one round per loop step: r× the HBM traffic."""
     b, f = spmv_relax_model(q, v, d_width)
     r = max(rounds, 1)
     return b * r, f * r

@@ -30,6 +30,8 @@ def main() -> int:
                     help="directory for BENCH_<table>.json files")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import configure_compile_cache
+    configure_compile_cache()
     from benchmarks import (bench_baselines, bench_construction,
                             bench_k_sweep, bench_kernels, bench_mutation,
                             bench_path, bench_query, bench_serving,

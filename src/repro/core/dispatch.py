@@ -12,19 +12,22 @@ stages of Algorithm 1 route through here:
   stage 2 — label-seeded bidirectional core relaxation:
       ``CoreRelaxer`` — reference backend keeps the COO scatter-min
       wavefront (``core_relax``, bit-identical to the pre-dispatch
-      engine); kernel backends pick one of three routes at dispatch
-      time (``CoreRelaxer.mode``, see docs/KERNELS.md):
+      engine); kernel backends pick one of three routes from the core's
+      size, density and ELL width (``CoreRelaxer.mode``, see
+      docs/KERNELS.md):
 
-      "fused"    — the default: one ``fused_relax_kernel`` launch runs
-                   ALL rounds with both stacked frontiers resident in
-                   VMEM and the fixed-point exit inside the kernel.
-      "dense"    — small dense cores (density >= ISLABEL_DENSE_THRESHOLD
-                   and n_core <= dense_cap) relax via the
-                   ``minplus_matmul`` kernel against a 0-diagonal dense
-                   adjacency: one tropical GEMM per round.
-      "ell_loop" — fallback when the fused working set would blow the
-                   VMEM budget: the legacy one-``spmv_relax``-launch-
-                   per-round ``lax.while_loop``.
+      "dense"   — small dense cores (density >= ISLABEL_DENSE_THRESHOLD
+                  and n_core <= dense_cap) relax via the
+                  ``minplus_matmul`` kernel against a 0-diagonal dense
+                  adjacency: one tropical GEMM per round.
+      "fused"   — small sparse cores (``fused_fits``): one
+                  ``fused_relax_kernel`` launch runs ALL rounds with
+                  both stacked frontiers resident in VMEM and the
+                  fixed-point exit inside the kernel.
+      "ell_xla" — every larger core: an XLA program, one ELL gather
+                  round per ``lax.while_loop`` step over a vertex-major
+                  frontier. Mosaic gathers only within one vreg, so no
+                  Pallas form of this step lowers at these sizes.
 
 Every route computes the same per-round fixed point (synchronous Jacobi
 Bellman-Ford over G_k), so answers agree bitwise: each round takes a min
@@ -54,13 +57,24 @@ from repro.core.labels import LabelRows
 from repro.kernels.backend import pallas_interpret, resolve_backend
 from repro.kernels.label_intersect import ops as li_ops
 from repro.kernels.minplus_matmul.kernel import minplus_matmul_kernel
-from repro.kernels.spmv_relax.kernel import (
-    fused_relax_kernel, fused_vmem_bytes, spmv_relax_kernel)
+from repro.kernels.spmv_relax.kernel import (FUSED_VMEM_BUDGET, LANES,
+                                             fused_relax_kernel,
+                                             fused_vmem_bytes)
 from repro.kernels.spmv_relax.ops import coo_to_ell
 
-# VMEM budget for the fused kernel's per-grid-step working set; above
-# this the dispatcher falls back to the per-round launch loop.
-FUSED_VMEM_BUDGET = 12 * 2 ** 20
+# Largest padded core the fused kernel takes: its in-vreg gather scans
+# every source tile, so a round costs O((V/128)^2 · D) vreg ops. Above
+# this the XLA gather round relaxes the core. The crossover between the
+# two on a chip is not measured; this matches the dense route's cap.
+FUSED_MAX_V = 2048
+
+
+def fused_fits(vp: int, width: int, bq: int,
+               vmem_budget: int = FUSED_VMEM_BUDGET) -> bool:
+    """Whether a core padded to ``vp`` vertices with ELL ``width`` takes
+    the fused kernel (shared by ``CoreRelaxer`` and version families)."""
+    return (vp <= FUSED_MAX_V
+            and fused_vmem_bytes(vp, width, bq) <= vmem_budget)
 
 
 @partial(jax.jit, static_argnames=("n_sentinel", "backend"))
@@ -118,23 +132,45 @@ def core_relax(seed_s, seed_t, ce_src, ce_dst, ce_w, mu,
         return jnp.minimum(mu, through_core), ds, dt, rounds
 
 
-@partial(jax.jit,
-         static_argnames=("n_core", "max_rounds", "interpret", "bq", "bv"))
-def _core_relax_ell(seed_s, seed_t, nbr_ids, nbr_w, mu, n_core: int,
-                    max_rounds: int, interpret: bool, bq: int, bv: int):
-    """Kernel-path relaxation: both frontiers stacked into one [2Q, Vp]
-    matrix, one ``spmv_relax`` launch per wavefront round."""
-    q, v = seed_s.shape
-    vp = nbr_ids.shape[0]                     # V padded to a bv multiple
-    rows = 2 * q
-    rp = -(-rows // bq) * bq
+def _stack(seed_s, seed_t, rows_to: int, cols_to: int):
+    """Both frontiers stacked into one [2Q, V] matrix, +inf padded."""
     d0 = jnp.concatenate([seed_s, seed_t], axis=0)
-    d0 = jnp.pad(d0, ((0, rp - rows), (0, vp - v)), constant_values=jnp.inf)
+    rows, v = d0.shape
+    return jnp.pad(d0, ((0, rows_to - rows), (0, cols_to - v)),
+                   constant_values=jnp.inf)
+
+
+def _finish(d, mu, q: int, v: int, n_core: int):
+    """Unstack [2Q(+pad), V(+pad)] and meet the two frontiers."""
+    ds = d[:q, :v]
+    dt = d[q:2 * q, :v]
+    through_core = jnp.min(ds[:, :n_core] + dt[:, :n_core], axis=1)
+    return jnp.minimum(mu, through_core), ds, dt
+
+
+def ell_round(d, nbr_ids_t, nbr_w_t):
+    """One synchronous ELL relaxation round on a vertex-major [Vp, R]
+    frontier: d'[v] = min(d[v], min_j d[nbr[j, v]] + w[j, v]), one row
+    gather per slot so nothing of size [Vp, R, D] is materialized."""
+    def slot(j, cand):
+        return jnp.minimum(cand, d[nbr_ids_t[j]] + nbr_w_t[j][:, None])
+    return jax.lax.fori_loop(0, nbr_ids_t.shape[0], slot, d)
+
+
+@partial(jax.jit, static_argnames=("n_core", "max_rounds"))
+def _core_relax_ell(seed_s, seed_t, nbr_ids, nbr_w, mu, n_core: int,
+                    max_rounds: int):
+    """XLA relaxation: both frontiers stacked vertex-major into one
+    [Vp, 2Q] matrix (a relaxation step gathers whole rows), one
+    ``ell_round`` per ``lax.while_loop`` step."""
+    q, v = seed_s.shape
+    vp = nbr_ids.shape[0]
+    d0 = _stack(seed_s, seed_t, 2 * q, vp).T
+    ids_t, w_t = nbr_ids.T, nbr_w.T
 
     def body(state):
         d, it, _ = state
-        d2 = spmv_relax_kernel(d, nbr_ids, nbr_w, bq=bq, bv=bv,
-                               interpret=interpret)
+        d2 = ell_round(d, ids_t, w_t)
         return d2, it + 1, jnp.any(d2 < d)
 
     def cond(state):
@@ -144,10 +180,7 @@ def _core_relax_ell(seed_s, seed_t, nbr_ids, nbr_w, mu, n_core: int,
     with jax.named_scope("islabel.core_relax_ell"):
         d, rounds, _ = jax.lax.while_loop(
             cond, body, (d0, jnp.int32(0), jnp.bool_(True)))
-        ds = d[:q, :v]
-        dt = d[q:rows, :v]
-        through_core = jnp.min(ds[:, :n_core] + dt[:, :n_core], axis=1)
-        return jnp.minimum(mu, through_core), ds, dt, rounds
+        return (*_finish(d.T, mu, q, v, n_core), rounds)
 
 
 @partial(jax.jit,
@@ -160,20 +193,14 @@ def _core_relax_fused(seed_s, seed_t, nbr_ids, nbr_w, mu, n_core: int,
     one round, real blocks freeze bitwise at their own fixed point)."""
     q, v = seed_s.shape
     vp = nbr_ids.shape[0]
-    rows = 2 * q
-    rp = -(-rows // bq) * bq
-    d0 = jnp.concatenate([seed_s, seed_t], axis=0)
-    d0 = jnp.pad(d0, ((0, rp - rows), (0, vp - v)), constant_values=jnp.inf)
+    d0 = _stack(seed_s, seed_t, -(-2 * q // bq) * bq, vp)
 
     with jax.named_scope("islabel.core_relax_fused"):
-        d, blk_rounds = fused_relax_kernel(d0, nbr_ids, nbr_w,
-                                           max_rounds=max_rounds, bq=bq,
-                                           interpret=interpret)
+        d, blk_rounds = fused_relax_kernel(
+            d0, nbr_ids.T, nbr_w.T, max_rounds=max_rounds, bq=bq,
+            interpret=interpret)
         rounds = jnp.max(blk_rounds, initial=0).astype(jnp.int32)
-        ds = d[:q, :v]
-        dt = d[q:rows, :v]
-        through_core = jnp.min(ds[:, :n_core] + dt[:, :n_core], axis=1)
-        return jnp.minimum(mu, through_core), ds, dt, rounds
+        return (*_finish(d, mu, q, v, n_core), rounds)
 
 
 @partial(jax.jit,
@@ -185,10 +212,7 @@ def _core_relax_dense(seed_s, seed_t, adj, mu, n_core: int,
     keep-old term, so ``minplus(d, adj)`` IS the synchronous round)."""
     q, v = seed_s.shape
     vp = adj.shape[0]
-    rows = 2 * q
-    rp = -(-rows // bm) * bm
-    d0 = jnp.concatenate([seed_s, seed_t], axis=0)
-    d0 = jnp.pad(d0, ((0, rp - rows), (0, vp - v)), constant_values=jnp.inf)
+    d0 = _stack(seed_s, seed_t, -(-2 * q // bm) * bm, vp)
 
     def body(state):
         d, it, _ = state
@@ -202,10 +226,7 @@ def _core_relax_dense(seed_s, seed_t, adj, mu, n_core: int,
     with jax.named_scope("islabel.core_relax_dense"):
         d, rounds, _ = jax.lax.while_loop(
             cond, body, (d0, jnp.int32(0), jnp.bool_(True)))
-        ds = d[:q, :v]
-        dt = d[q:rows, :v]
-        through_core = jnp.min(ds[:, :n_core] + dt[:, :n_core], axis=1)
-        return jnp.minimum(mu, through_core), ds, dt, rounds
+        return (*_finish(d, mu, q, v, n_core), rounds)
 
 
 class CoreRelaxer:
@@ -213,20 +234,21 @@ class CoreRelaxer:
 
     Holds the COO edge arrays (local indices in [0, n_core), weights)
     and lazily derives the kernel-side layouts: the ELL planes the
-    per-round and fused kernels consume, and (for dense cores) the
+    fused kernel and the XLA gather round consume, and (for dense cores) the
     0-diagonal dense adjacency for ``minplus_matmul`` — each built once
     per index on first kernel-path query, padded to lane-aligned vertex
     counts so launches need no reshaping.
 
     Kernel-route selection (``.mode``) happens at dispatch time:
     density >= ``dense_threshold`` (env ``ISLABEL_DENSE_THRESHOLD``)
-    with n_core <= ``dense_cap`` -> "dense"; else "fused" when the fused
-    working set fits the VMEM budget; else "ell_loop". Set env
-    ``ISLABEL_FUSED_RELAX=0`` to force the legacy per-round loop.
+    with n_core <= ``dense_cap`` -> "dense"; else "fused" when
+    ``fused_fits`` (small core, working set inside the VMEM budget);
+    else "ell_xla". Set env ``ISLABEL_FUSED_RELAX=0`` to skip the fused
+    kernel.
     """
 
     def __init__(self, ce_src, ce_dst, ce_w, n_core: int, *,
-                 bq: int = 8, bv: int = 128, d_width: int = 16,
+                 bq: int = 8, d_width: int = 16,
                  fused: bool | None = None,
                  dense_threshold: float | None = None,
                  dense_cap: int = 2048,
@@ -236,7 +258,6 @@ class CoreRelaxer:
         self.ce_w = ce_w
         self.n_core = n_core
         self.bq = bq
-        self.bv = bv
         self.d_width = d_width
         if fused is None:
             fused = os.environ.get("ISLABEL_FUSED_RELAX", "1") != "0"
@@ -254,7 +275,7 @@ class CoreRelaxer:
 
     @property
     def mode(self) -> str:
-        """Kernel route: "dense" | "fused" | "ell_loop" (reference
+        """Stage-2 route: "dense" | "fused" | "ell_xla" (reference
         backend bypasses this entirely)."""
         if self._mode is None:
             if (0 < self.n_core <= self.dense_cap
@@ -263,11 +284,10 @@ class CoreRelaxer:
             elif self.fused:
                 nbr_ids, _ = self.ell()
                 vp, width = nbr_ids.shape
-                fits = fused_vmem_bytes(vp, width, self.bq) \
-                    <= self.vmem_budget
-                self._mode = "fused" if fits else "ell_loop"
+                fits = fused_fits(vp, width, self.bq, self.vmem_budget)
+                self._mode = "fused" if fits else "ell_xla"
             else:
-                self._mode = "ell_loop"
+                self._mode = "ell_xla"
         return self._mode
 
     def dense_adj(self):
@@ -277,7 +297,7 @@ class CoreRelaxer:
         sentinel and lane padding so parked values survive each round."""
         if self._adj is None:
             v = self.n_core + 1
-            vp = -(-v // self.bv) * self.bv
+            vp = -(-v // LANES) * LANES
             adj = np.full((vp, vp), np.inf, np.float32)
             src = np.asarray(self.ce_src)
             dst = np.asarray(self.ce_dst)
@@ -295,11 +315,11 @@ class CoreRelaxer:
 
     def ell(self):
         """(nbr_ids [Vp, D], nbr_w [Vp, D]) with Vp = n_core+1 rounded up
-        to a multiple of bv (sentinel column included, padding rows
+        to a multiple of 128 (sentinel column included, padding rows
         edgeless)."""
         if self._ell is None:
             v = self.n_core + 1
-            vp = -(-v // self.bv) * self.bv
+            vp = -(-v // LANES) * LANES
             with jax.ensure_compile_time_eval():
                 ids, ws = coo_to_ell(v, np.asarray(self.ce_src),
                                      np.asarray(self.ce_dst),
@@ -329,6 +349,5 @@ class CoreRelaxer:
             return _core_relax_fused(seed_s, seed_t, nbr_ids, nbr_w, mu,
                                      self.n_core, max_rounds, interpret,
                                      self.bq)
-        return _core_relax_ell(
-            seed_s, seed_t, nbr_ids, nbr_w, mu, self.n_core, max_rounds,
-            interpret, self.bq, self.bv)
+        return _core_relax_ell(seed_s, seed_t, nbr_ids, nbr_w, mu,
+                               self.n_core, max_rounds)

@@ -129,15 +129,25 @@ def encoded_nbytes(delta, base, d_enc) -> int:
 
 
 # --------------------------------------------------------------- decode
-def decode_ids(delta, base, n_sentinel: int):
+def _cumsum_last(x):
+    return jnp.cumsum(x, axis=-1)
+
+
+def decode_ids(delta, base, n_sentinel: int, cumsum=_cumsum_last):
     """int16 deltas + int32 base -> sorted int32 ids (pads -> sentinel).
 
-    Pure jnp (cumsum over the last axis) so it runs unchanged inside
-    the Pallas kernel body, the interpret backend, and the reference.
+    ``base`` holds one id per row, as ``[...]`` or ``[..., 1]``. Pure
+    jnp over an inclusive prefix sum along the last axis, so it runs
+    unchanged in the interpret backend, the reference, and — given the
+    kernel's lane prefix sum as ``cumsum`` (Mosaic lowers no cumsum) —
+    inside the Pallas kernel body.
     """
-    pad = jnp.cumsum((delta < 0).astype(jnp.int32), axis=-1) > 0
-    steps = jnp.where(pad, 0, delta.astype(jnp.int32))
-    ids = base[..., None].astype(jnp.int32) + jnp.cumsum(steps, axis=-1)
+    delta = delta.astype(jnp.int32)
+    if base.ndim < delta.ndim:
+        base = base[..., None]
+    pad = cumsum((delta < 0).astype(jnp.int32)) > 0
+    steps = jnp.where(pad, 0, delta)
+    ids = base.astype(jnp.int32) + cumsum(steps)
     return jnp.where(pad, jnp.int32(n_sentinel), ids)
 
 

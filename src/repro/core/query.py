@@ -15,9 +15,11 @@ queries per launch. μ still prunes: converged queries stop contributing
 improvements, and the final min with μ implements Line 19.
 
 Both stages execute through the kernel dispatch layer
-(``repro.core.dispatch``): stage 1 via the tiled-equality-join Pallas
+(``repro.core.dispatch``): stage 1 via the equality-join Pallas
 label-intersect kernel (jnp searchsorted reference off-TPU), stage 2 via
-the ELL min-plus ``spmv_relax`` kernel (COO scatter reference off-TPU).
+the route ``CoreRelaxer.mode`` picks — the dense or fused Pallas kernel
+for small cores, an XLA ELL gather round for large ones (COO scatter
+reference off-TPU).
 ``query_chunk`` tiles large batches so the dense per-direction frontier
 is ``[chunk, n_core+1]``, never ``[Q, n_core+1]``.
 """

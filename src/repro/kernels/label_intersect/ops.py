@@ -9,12 +9,12 @@ import jax.numpy as jnp
 from repro.core.labels import PAD_D, PAD_DELTA, LabelRows, decode_rows
 from repro.kernels.backend import pallas_interpret, resolve_backend
 from repro.kernels.label_intersect.kernel import (
-    label_intersect_kernel, label_intersect_packed_kernel)
+    LANES, label_intersect_kernel, label_intersect_packed_kernel)
 from repro.kernels.label_intersect.ref import label_intersect_ref
 
 
 def label_intersect(ids_s, d_s, ids_t, d_t, n_sentinel: int, *,
-                    bq=8, chunk=128, backend=None, interpret=None):
+                    bq=8, backend=None, interpret=None):
     backend = resolve_backend(backend, interpret)
     if backend == "reference":
         return label_intersect_ref(ids_s.astype(jnp.int32),
@@ -23,7 +23,7 @@ def label_intersect(ids_s, d_s, ids_t, d_t, n_sentinel: int, *,
                                    d_t.astype(jnp.float32), n_sentinel)
     q, l = ids_s.shape
     qp = -(-q // bq) * bq
-    lp = -(-l // chunk) * chunk
+    lp = -(-l // LANES) * LANES
 
     def padi(x):
         return jnp.pad(x, ((0, qp - q), (0, lp - l)),
@@ -35,14 +35,13 @@ def label_intersect(ids_s, d_s, ids_t, d_t, n_sentinel: int, *,
     mu = label_intersect_kernel(
         padi(ids_s.astype(jnp.int32)), padd(d_s.astype(jnp.float32)),
         padi(ids_t.astype(jnp.int32)), padd(d_t.astype(jnp.float32)),
-        n_sentinel=n_sentinel, bq=bq, chunk=chunk,
-        interpret=pallas_interpret(backend))
-    return mu[:q]
+        n_sentinel=n_sentinel, bq=bq, interpret=pallas_interpret(backend))
+    return mu[:q, 0]
 
 
 def label_intersect_rows(rows_s: LabelRows, rows_t: LabelRows,
                          n_sentinel: int, *, codec: str = "none",
-                         bq=8, chunk=128, backend=None):
+                         bq=8, backend=None):
     """μ over gathered ``LabelRows`` in either codec.
 
     codec "none" routes to the plain wrapper; "delta16" pads the
@@ -51,8 +50,7 @@ def label_intersect_rows(rows_s: LabelRows, rows_t: LabelRows,
     backend decodes with jnp and reuses the searchsorted merge."""
     if codec == "none":
         return label_intersect(rows_s.ids, rows_s.d, rows_t.ids, rows_t.d,
-                               n_sentinel, bq=bq, chunk=chunk,
-                               backend=backend)
+                               n_sentinel, bq=bq, backend=backend)
     backend = resolve_backend(backend)
     if backend == "reference":
         ids_s, d_s = decode_rows(rows_s, n_sentinel, codec)
@@ -61,7 +59,7 @@ def label_intersect_rows(rows_s: LabelRows, rows_t: LabelRows,
     bq = max(bq, 16)                 # int16 planes tile at (16, 128)
     q, l = rows_s.ids.shape
     qp = -(-q // bq) * bq
-    lp = -(-l // chunk) * chunk
+    lp = -(-l // LANES) * LANES
 
     def pad_delta(x):
         return jnp.pad(x, ((0, qp - q), (0, lp - l)),
@@ -71,12 +69,11 @@ def label_intersect_rows(rows_s: LabelRows, rows_t: LabelRows,
         fill = jnp.inf if x.dtype == jnp.float32 else PAD_D
         return jnp.pad(x, ((0, qp - q), (0, lp - l)), constant_values=fill)
 
-    def pad_base(x):
-        return jnp.pad(x, (0, qp - q))
+    def pad_base(x):                 # [Q] -> [Qp, 1]: a 2-D kernel block
+        return jnp.pad(x, (0, qp - q))[:, None]
 
     mu = label_intersect_packed_kernel(
         pad_delta(rows_s.ids), pad_base(rows_s.base), pad_d(rows_s.d),
         pad_delta(rows_t.ids), pad_base(rows_t.base), pad_d(rows_t.d),
-        n_sentinel=n_sentinel, bq=bq, chunk=chunk,
-        interpret=pallas_interpret(backend))
-    return mu[:q]
+        n_sentinel=n_sentinel, bq=bq, interpret=pallas_interpret(backend))
+    return mu[:q, 0]

@@ -21,16 +21,17 @@ def _minplus_kernel(a_ref, b_ref, o_ref):
     """Grid = (M/bm, N/bn, K/bk); K innermost (default row-major order)."""
     @pl.when(pl.program_id(2) == 0)
     def _init():
-        o_ref[...] = jnp.full_like(o_ref, jnp.inf)
+        o_ref[...] = jnp.full(o_ref.shape, jnp.inf, o_ref.dtype)
 
     a = a_ref[...]                      # [bm, bk]
     b = b_ref[...]                      # [bk, bn]
-    # min over k of a[i,k]+b[k,j]; fori over bk keeps the VMEM footprint
-    # at bm*bn instead of bm*bk*bn.
-    def body(k, acc):
-        return jnp.minimum(acc, a[:, k][:, None] + b[k, :][None, :])
-    acc = jax.lax.fori_loop(0, a.shape[1], body,
-                            jnp.full(o_ref.shape, jnp.inf, o_ref.dtype))
+    # min over k of a[i,k]+b[k,j], unrolled over the static bk: column k
+    # of a broadcasts across lanes, row k of b across sublanes, and the
+    # VMEM footprint stays at bm*bn instead of bm*bk*bn. (A traced k
+    # would need a value-level dynamic slice, which Mosaic cannot lower.)
+    acc = jnp.full(o_ref.shape, jnp.inf, o_ref.dtype)
+    for k in range(a.shape[1]):
+        acc = jnp.minimum(acc, a[:, k:k + 1] + b[k:k + 1, :])
     o_ref[...] = jnp.minimum(o_ref[...], acc)
 
 

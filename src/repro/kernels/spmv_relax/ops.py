@@ -1,13 +1,9 @@
-"""Wrapper: COO core graph -> ELL (fixed-width in-neighbor lists) +
-padding + backend-aware kernel invocation."""
+"""COO core graph -> ELL (fixed-width in-neighbor lists), the layout
+every stage-2 route but the COO reference consumes."""
 from __future__ import annotations
 
 import jax.numpy as jnp
 import numpy as np
-
-from repro.kernels.backend import pallas_interpret, resolve_backend
-from repro.kernels.spmv_relax.kernel import spmv_relax_kernel
-from repro.kernels.spmv_relax.ref import spmv_relax_ref
 
 
 def ell_layout(n_v: int, dst, d_width: int = 16):
@@ -48,19 +44,3 @@ def coo_to_ell(n_v: int, src, dst, w, d_width: int = 16):
         ws[rows, slots] = w[order]
     return jnp.asarray(ids), jnp.asarray(ws)
 
-
-def spmv_relax(dist, nbr_ids, nbr_w, *, bq=8, bv=128, backend=None,
-               interpret=None):
-    backend = resolve_backend(backend, interpret)
-    if backend == "reference":
-        return spmv_relax_ref(dist.astype(jnp.float32), nbr_ids, nbr_w)
-    q, v = dist.shape
-    qp = -(-q // bq) * bq
-    vp = -(-v // bv) * bv
-    dist_p = jnp.pad(dist.astype(jnp.float32), ((0, qp - q), (0, vp - v)),
-                     constant_values=jnp.inf)
-    ids_p = jnp.pad(nbr_ids, ((0, vp - v), (0, 0)))
-    w_p = jnp.pad(nbr_w, ((0, vp - v), (0, 0)), constant_values=jnp.inf)
-    out = spmv_relax_kernel(dist_p, ids_p, w_p, bq=bq, bv=bv,
-                            interpret=pallas_interpret(backend))
-    return out[:q, :v]

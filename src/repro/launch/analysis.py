@@ -19,15 +19,6 @@ _DTYPE_BYTES = {"f64": 8, "f32": 4, "f16": 2, "bf16": 2, "s64": 8, "u64": 8,
                 "pred": 1, "f8e4m3fn": 1, "f8e5m2": 1}
 
 
-def cost_dict(compiled) -> dict:
-    """Normalize ``Compiled.cost_analysis()`` across JAX versions: older
-    releases return a dict, newer ones a list with one dict per module."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
-
-
 def _tuple_shapes(type_str: str):
     """Parse all array types out of an HLO result type string."""
     out = []

@@ -26,7 +26,7 @@ from repro.configs import registry
 from repro.launch.mesh import make_production_mesh
 
 from repro.launch.analysis import (HBM_BW, ICI_BW, PEAK_FLOPS,  # noqa: E402
-                                   collective_bytes, cost_dict)
+                                   collective_bytes)
 
 
 def _probe_specs(spec):
@@ -56,7 +56,7 @@ def _compile_costs(spec, shape, mesh):
     from repro.train.steps import build_bundle
     with mesh:
         compiled = build_bundle(spec, shape, mesh).lower().compile()
-    cost = cost_dict(compiled)
+    cost = compiled.cost_analysis()
     coll = collective_bytes(compiled.as_text())
     return (float(cost.get("flops", 0.0)),
             float(cost.get("bytes accessed", 0.0)), coll)
@@ -80,7 +80,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool, out_dir: Path,
             compiled = lowered.compile()
             t2 = time.perf_counter()
         mem = compiled.memory_analysis()
-        cost = cost_dict(compiled)
+        cost = compiled.cost_analysis()
         hlo = compiled.as_text()
         coll = collective_bytes(hlo)
 

@@ -19,7 +19,7 @@ import jax
 
 from repro.configs import registry
 from repro.launch.analysis import (HBM_BW, ICI_BW, PEAK_FLOPS,
-                                   collective_bytes, cost_dict)
+                                   collective_bytes)
 from repro.launch.dryrun import _compile_costs, _probe_specs
 from repro.launch.mesh import make_production_mesh
 
@@ -108,7 +108,7 @@ def run_variant(arch, shape, multi_pod, model_over, bundle_over, spec_over,
         with mesh:
             compiled = build_bundle(spec, shape, mesh,
                                     overrides=bundle_over).lower().compile()
-        cost = cost_dict(compiled)
+        cost = compiled.cost_analysis()
         mem = compiled.memory_analysis()
         coll = collective_bytes(compiled.as_text())
         flops = float(cost.get("flops", 0.0))
@@ -124,8 +124,8 @@ def run_variant(arch, shape, multi_pod, model_over, bundle_over, spec_over,
                 with mesh:
                     c = bb(s, shape, mesh, overrides=probe_over) \
                         .lower().compile()
-                return (float(cost_dict(c).get("flops", 0)),
-                        float(cost_dict(c).get("bytes accessed", 0)),
+                return (float(c.cost_analysis().get("flops", 0)),
+                        float(c.cost_analysis().get("bytes accessed", 0)),
                         collective_bytes(c.as_text()))
             f_lo, b_lo, c_lo = _with(lo)
             f_hi, b_hi, c_hi = _with(hi)

@@ -115,18 +115,17 @@ class _ObsSession:
         device_memory_gauges()
         if server.versions is not None:
             version_family_gauges(server.versions, server=server.name)
-        if self.watcher.supported:
-            by_region = self.watcher.snapshot()
-            print(f"  xla compiles by region: {by_region}")
-            reads = self.watcher.count("serve_read")
-            if require_zero_read_compiles:
-                if reads:
-                    print(f"  AUDIT FAIL: {reads} XLA backend compiles on "
-                          f"the read path after warmup")
-                    failures += 1
-                else:
-                    print("  audit[compile-events]: 0 backend compiles in "
-                          "region serve_read across the replay")
+        by_region = self.watcher.snapshot()
+        print(f"  xla compiles by region: {by_region}")
+        reads = self.watcher.count("serve_read")
+        if require_zero_read_compiles:
+            if reads:
+                print(f"  AUDIT FAIL: {reads} XLA backend compiles on "
+                      f"the read path after warmup")
+                failures += 1
+            else:
+                print("  audit[compile-events]: 0 backend compiles in "
+                      "region serve_read across the replay")
         if self.tracer.enabled:
             cov = self.tracer.request_coverage()
             print(f"  trace: {len(self.tracer.finished())} spans; request "
@@ -706,6 +705,8 @@ def main():
                     help="wrap the replay in jax.profiler.trace writing "
                          "to this directory (TensorBoard/Perfetto)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import configure_compile_cache
+    configure_compile_cache()
     if args.mode == "lm":
         serve_lm(args)
     elif args.mode == "mutate":

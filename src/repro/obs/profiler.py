@@ -20,9 +20,8 @@ is equal. The serving engine tags its execution windows with
 ``launch/serve.py --mode mutate`` exits nonzero if ``serve_read``
 compiles are ever counted after warmup.
 
-On JAX builds without ``jax.monitoring`` listener support the watcher
-degrades to inactive (``supported = False``) — the cache-size probes in
-``DistanceServer.compile_cache_sizes()`` remain the fallback gate.
+The cache-size probes in ``DistanceServer.compile_cache_sizes()`` are
+a second, independent gate.
 """
 from __future__ import annotations
 
@@ -74,7 +73,6 @@ class CompileWatcher:
             "obs.xla_compiles", "XLA backend compiles by region")
         self.compile_seconds = self.registry.counter(
             "obs.xla_compile_seconds", "XLA backend compile wall time")
-        self.supported = False
         self._active = False
 
     # ------------------------------------------------------- listener
@@ -88,12 +86,7 @@ class CompileWatcher:
     def start(self) -> "CompileWatcher":
         if self._active:
             return self
-        try:
-            jax.monitoring.register_event_duration_secs_listener(
-                self._on_event)
-            self.supported = True
-        except Exception:
-            self.supported = False
+        jax.monitoring.register_event_duration_secs_listener(self._on_event)
         self._active = True
         return self
 
@@ -101,13 +94,7 @@ class CompileWatcher:
         if not self._active:
             return
         self._active = False
-        if self.supported:
-            try:
-                from jax._src import monitoring as _mon
-                _mon._unregister_event_duration_listener_by_callback(
-                    self._on_event)
-            except Exception:
-                pass      # listener stays registered but inert (_active)
+        jax.monitoring.unregister_event_duration_listener(self._on_event)
 
     def __enter__(self):
         return self.start()
@@ -189,18 +176,13 @@ def version_family_gauges(manager, registry=None, server: str = "default"
 # ------------------------------------------------------------ profiler
 @contextlib.contextmanager
 def profiler_session(log_dir: str | None):
-    """``jax.profiler.trace`` wrapper: a no-op when ``log_dir`` is falsy
-    or this JAX build lacks the profiler, so call sites need no
-    branching. The written trace opens in TensorBoard / Perfetto and
+    """``jax.profiler.trace`` wrapper: a no-op when ``log_dir`` is falsy,
+    so call sites need no branching; a profiler that fails to start
+    raises. The written trace opens in TensorBoard / Perfetto and
     carries the ``jax.named_scope`` annotations the kernel dispatch
     layer emits (islabel.label_intersect / islabel.core_relax*)."""
     if not log_dir:
         yield False
         return
-    try:
-        ctx = jax.profiler.trace(str(log_dir))
-    except Exception:
-        yield False
-        return
-    with ctx:
+    with jax.profiler.trace(str(log_dir)):
         yield True

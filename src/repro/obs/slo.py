@@ -314,8 +314,7 @@ def compiles_source(watcher, region: str = "serve_read"):
     backend compile counted in ``region`` is a bad event (and there are
     no good ones), so any compile inside the window burns at rate 1."""
     def probe():
-        bad = int(watcher.count(region)) if watcher.supported else 0
-        return 0, bad
+        return 0, int(watcher.count(region))
     return probe
 
 

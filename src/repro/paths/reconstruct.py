@@ -21,7 +21,7 @@ Stages (all inside one jitted function, see ``engine.PathEngine``):
      (exact float equality — at the Bellman-Ford fixed point the min is
      attained, so a parent always exists unless v is a label seed,
      ``DS[v] == seed[v]``, which ends the chase). Each chase step is a
-     ``[Q, D]`` gather over the same ELL layout ``spmv_relax`` consumes
+     ``[Q, D]`` gather over the same ELL layout stage 2 consumes
      (with a via plane added), so no ``[Q, V, D]`` tensor is ever
      materialized and no extra state is carried through the relaxation.
 

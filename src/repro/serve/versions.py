@@ -53,15 +53,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.dispatch import (FUSED_VMEM_BUDGET, _core_relax_ell,
-                                 _core_relax_fused, core_relax,
+from repro.core.dispatch import (_core_relax_ell, _core_relax_fused,
+                                 core_relax, fused_fits,
                                  label_intersect_rows_dispatch)
 from repro.core.index import (ISLabelIndex, apply_delete_host,
                               apply_insert_host)
 from repro.core.labels import (LabelCompressionError, LabelRows,
                                decode_rows, encode_labels)
 from repro.kernels.backend import pallas_interpret, resolve_backend
-from repro.kernels.spmv_relax.kernel import fused_vmem_bytes
+from repro.kernels.spmv_relax.kernel import LANES
 from repro.kernels.spmv_relax.ops import ell_layout
 
 __all__ = [
@@ -122,7 +122,7 @@ class VersionFamily:
     """
 
     def __init__(self, n: int, core_cap: int, edge_cap: int,
-                 ell_width: int, *, bq: int = 8, bv: int = 128,
+                 ell_width: int, *, bq: int = 8,
                  codec: str = "none", d_dtype: str | None = None):
         if core_cap < 1:
             raise ValueError("core_cap must be >= 1")
@@ -131,17 +131,16 @@ class VersionFamily:
         self.edge_cap = edge_cap
         self.ell_width = ell_width
         self.bq = bq
-        self.bv = bv
-        self.vp = -(-(core_cap + 1) // bv) * bv
+        self.vp = -(-(core_cap + 1) // LANES) * LANES
         self.max_rounds = core_cap          # while_loop exits at fixpoint
         # label codec pin: every version of the family must encode the
         # same way or the state dtypes (and the compiled fns) would move
         self.codec = codec
         self.d_dtype = d_dtype
-        # fused single-launch relaxation unless the family's pinned ELL
-        # working set exceeds the VMEM budget (then per-round launches)
-        self.relax_mode = ("fused" if fused_vmem_bytes(
-            self.vp, ell_width, bq) <= FUSED_VMEM_BUDGET else "ell_loop")
+        # fused single-launch relaxation when the family's pinned core
+        # and ELL width admit it, else the XLA gather round
+        self.relax_mode = ("fused" if fused_fits(self.vp, ell_width, bq)
+                           else "ell_xla")
         self._mu_fns: dict = {}
         self._full_fns: dict = {}
 
@@ -172,7 +171,7 @@ class VersionFamily:
         backend = resolve_backend(backend)
         if backend not in self._full_fns:
             n, cap, codec = self.n, self.core_cap, self.codec
-            max_rounds, bq, bv = self.max_rounds, self.bq, self.bv
+            max_rounds, bq = self.max_rounds, self.bq
             interp = False if backend == "reference" \
                 else pallas_interpret(backend)
 
@@ -204,7 +203,7 @@ class VersionFamily:
                 else:
                     ans, _, _, rounds = _core_relax_ell(
                         seed_s, seed_t, state.nbr_ids, state.nbr_w, mu,
-                        cap, max_rounds, interp, bq, bv)
+                        cap, max_rounds)
                 return ans, rounds
 
             self._full_fns[backend] = jax.jit(run)

@@ -32,7 +32,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.dispatch import (CoreRelaxer,
@@ -124,7 +123,7 @@ class ShardedQueryEngine:
 
         # rounds is bitwise-identical across shards (identical seeds in
         # the real columns -> identical relaxation), so out_spec P()
-        # with check_rep=False just adopts the replicated value.
+        # with check_vma=False just adopts the replicated value.
         if self.codec == "none":
             def shard_fn(blk_ids, blk_d, s, t):
                 # the per-device block keeps a leading axis of size 1
@@ -132,9 +131,9 @@ class ShardedQueryEngine:
                     LabelRows(blk_ids[0], None, blk_d[0]), s, t,
                     backend, mu_only)
 
-            mapped = shard_map(shard_fn, mesh=self.mesh,
-                               in_specs=(blocks, blocks, P(), P()),
-                               out_specs=out_specs, check_rep=False)
+            mapped = jax.shard_map(shard_fn, mesh=self.mesh,
+                                   in_specs=(blocks, blocks, P(), P()),
+                                   out_specs=out_specs, check_vma=False)
 
             def run(s, t):
                 return mapped(self.lbl_ids, self.lbl_d,
@@ -148,10 +147,10 @@ class ShardedQueryEngine:
                     LabelRows(blk_ids[0], blk_base[0], blk_d[0]), s, t,
                     backend, mu_only)
 
-            mapped = shard_map(
+            mapped = jax.shard_map(
                 shard_fn, mesh=self.mesh,
                 in_specs=(blocks, base_blocks, blocks, P(), P()),
-                out_specs=out_specs, check_rep=False)
+                out_specs=out_specs, check_vma=False)
 
             def run(s, t):
                 return mapped(self.enc_ids, self.enc_base, self.enc_d,

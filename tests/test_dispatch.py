@@ -6,7 +6,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core import ISLabelIndex, IndexConfig, ref
-from repro.core.dispatch import CoreRelaxer, core_relax
+from repro.core.dispatch import FUSED_MAX_V, CoreRelaxer, core_relax
 from repro.graphs import generators as gen
 from repro.kernels.backend import ENV_VAR, resolve_backend
 
@@ -158,7 +158,8 @@ def _random_core(v, e, seed=0):
 def test_dispatch_density_routing():
     """Route selection: density >= threshold with a small core picks the
     minplus dense route; sparse cores pick the fused kernel; the VMEM
-    budget and the fused kill-switch both fall back to the launch loop."""
+    budget, the core-size cap and the fused kill-switch all send the
+    core to the XLA gather round."""
     v = 100
     dense_edges = _random_core(v, int(0.1 * v * v))
     sparse_edges = _random_core(v, 2 * v, seed=1)
@@ -169,12 +170,16 @@ def test_dispatch_density_routing():
                        dense_threshold=0.5).mode == "fused"
     # core too big for the dense route even when dense enough
     assert CoreRelaxer(*dense_edges, v, dense_cap=50).mode == "fused"
-    # fused kill-switch -> legacy per-round loop
+    # fused kill-switch -> XLA gather round
     assert CoreRelaxer(*sparse_edges, v, fused=False,
-                       dense_threshold=2.0).mode == "ell_loop"
-    # fused working set over the VMEM budget -> loop fallback
+                       dense_threshold=2.0).mode == "ell_xla"
+    # fused working set over the VMEM budget -> XLA gather round
     assert CoreRelaxer(*sparse_edges, v, dense_threshold=2.0,
-                       vmem_budget=1).mode == "ell_loop"
+                       vmem_budget=1).mode == "ell_xla"
+    # core above the fused kernel's size cap -> XLA gather round
+    big = FUSED_MAX_V + 1
+    assert CoreRelaxer(*_random_core(big, 2 * big, seed=4),
+                       big).mode == "ell_xla"
 
 
 def test_dispatch_env_overrides(monkeypatch):
@@ -182,22 +187,22 @@ def test_dispatch_env_overrides(monkeypatch):
     dense_edges = _random_core(v, int(0.1 * v * v))
     monkeypatch.setenv("ISLABEL_FUSED_RELAX", "0")
     monkeypatch.setenv("ISLABEL_DENSE_THRESHOLD", "0.5")
-    assert CoreRelaxer(*dense_edges, v).mode == "ell_loop"
+    assert CoreRelaxer(*dense_edges, v).mode == "ell_xla"
     monkeypatch.delenv("ISLABEL_DENSE_THRESHOLD")
     monkeypatch.delenv("ISLABEL_FUSED_RELAX")
     assert CoreRelaxer(*dense_edges, v).mode == "dense"
 
 
-@pytest.mark.parametrize("force", ["dense", "fused", "ell_loop"])
+@pytest.mark.parametrize("force", ["dense", "fused", "ell_xla"])
 def test_all_kernel_routes_bitwise_equal_reference(force):
-    """Every kernel route (dense minplus GEMM, fused all-rounds kernel,
-    per-round launch loop) == the COO reference bitwise, with the same
-    round count."""
+    """Every stage-2 route (dense minplus GEMM, fused all-rounds kernel,
+    XLA gather round) == the COO reference bitwise, with the same round
+    count."""
     v, e, q = 120, 1450, 9           # density ~0.1: dense-eligible
     edges = _random_core(v, e, seed=2)
     kw = {"dense": dict(),
           "fused": dict(dense_threshold=2.0),
-          "ell_loop": dict(dense_threshold=2.0, fused=False)}[force]
+          "ell_xla": dict(dense_threshold=2.0, fused=False)}[force]
     relaxer = CoreRelaxer(*edges, v, **kw)
     assert relaxer.mode == force
     r = np.random.default_rng(3)

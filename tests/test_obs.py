@@ -327,8 +327,6 @@ def test_compare_dirs_requires_named_tables(tmp_path):
 # ------------------------------------------------------ compile watcher
 def test_compile_watcher_attributes_regions():
     with CompileWatcher() as w:
-        if not w.supported:
-            pytest.skip("jax.monitoring listeners unavailable")
         before = w.count("obs-test-zone")
 
         def f(x):
@@ -354,8 +352,6 @@ def test_zero_serve_read_compiles_across_version_swaps(index):
     ``serve_read`` (eager mutation scatters may compile — they land in
     region ``mutation``, never on the read path)."""
     with CompileWatcher() as w:
-        if not w.supported:
-            pytest.skip("jax.monitoring listeners unavailable")
         read0 = w.count("serve_read")
         srv = DistanceServer(index, versioned=True, buckets=(8, 32),
                              max_wait_ms=1.0, cache_size=1024)

@@ -171,15 +171,11 @@ def test_latency_source_threshold_and_server_filter():
 
 def test_compiles_source_counts_every_compile_as_bad():
     class FakeWatcher:
-        supported = True
-
         def count(self, region):
             return {"serve_read": 2}.get(region, 0)
 
     assert compiles_source(FakeWatcher())() == (0, 2)
     assert compiles_source(FakeWatcher(), region="other")() == (0, 0)
-    FakeWatcher.supported = False
-    assert compiles_source(FakeWatcher())() == (0, 0)
 
 
 def test_poll_path_fires_from_attached_source():
@@ -241,8 +237,6 @@ def test_default_serving_slos_cover_the_standing_objectives():
     eng = SLOEngine(specs, registry=MetricRegistry())
 
     class OneCompile:
-        supported = True
-
         def count(self, region):
             return 1
 
