@@ -59,19 +59,40 @@ class BuildStats:
     label_bytes: int = 0
     build_seconds: float = 0.0
     mis_rounds: list = dataclasses.field(default_factory=list)
-    # construction-phase split + sync accounting (docs/CONSTRUCTION.md)
+    # construction-phase split + sync accounting (docs/CONSTRUCTION.md);
+    # the seconds are the durations of the islabel.build.* spans
     peel_seconds: float = 0.0       # hierarchy (peel) phase wall time
     label_seconds: float = 0.0      # labeling phase wall time
+    assemble_seconds: float = 0.0   # label copy to host + core upload
+    compiles: int = 0               # XLA compiles during this build (a
+                                    # persistent-cache load counts too)
+    compile_seconds: float = 0.0    # their wall time (inside the phases)
     host_syncs: int = 0             # blocking device→host reads during build
     peel_loop_syncs: int = 0        # blocking reads inside the level loop
     peel_iters: int = 0             # level-loop iterations; the bench gates
                                     # peel_loop_syncs / peel_iters <= 1
     peak_device_bytes: int = 0      # max live device bytes observed (sampled)
+    # work against padding: real entries against the fixed-shape slots
+    # the device runs over
+    label_candidates: int = 0       # label join: each non-core vertex's
+                                    # self entry + its up-neighbours' labels
+    label_slots: int = 0            # Σ_levels chunks·label_chunk·(d_cap·l_cap+1)
+    peel_edges: int = 0             # Σ deduped edges over level iterations
+    peel_edge_slots: int = 0        # peel_iters · e_cap
+    peel_aug_edges: int = 0         # Σ IS-incident edges (augmentation)
+    peel_aug_slots: int = 0         # peel_iters · aug_cap
 
     def summary(self) -> str:
+        def pct(a, b):
+            return f"{100.0 * a / b:.2f}%" if b else "-"
         return (f"n={self.n} m={self.m} k={self.k} |V_Gk|={self.n_core} "
                 f"|E_Gk|={self.m_core} label_entries={self.label_entries} "
                 f"label_MB={self.label_bytes / 1e6:.2f} "
                 f"build_s={self.build_seconds:.2f} "
-                f"(peel {self.peel_seconds:.2f} + label {self.label_seconds:.2f}) "
-                f"host_syncs={self.host_syncs}")
+                f"(peel {self.peel_seconds:.2f} + label {self.label_seconds:.2f}"
+                f" + assemble {self.assemble_seconds:.2f}) "
+                f"host_syncs={self.host_syncs} "
+                f"compiles={self.compiles} ({self.compile_seconds:.2f} s) "
+                f"fill: label {pct(self.label_candidates, self.label_slots)} "
+                f"peel_aug {pct(self.peel_aug_edges, self.peel_aug_slots)} "
+                f"peel_edges {pct(self.peel_edges, self.peel_edge_slots)}")

@@ -37,6 +37,7 @@ from repro.core import sync as hsync
 from repro.core.config import IndexConfig
 from repro.core.mis import independent_set
 from repro.graphs import csr as gcsr
+from repro.obs.profiler import span
 
 
 @dataclasses.dataclass
@@ -60,6 +61,12 @@ class Hierarchy:
     host_syncs: int = 0         # blocking device→host reads in the level loop
     peel_iters: int = 0         # level-loop iterations (peel_level calls) —
                                 # the bench gate is host_syncs <= peel_iters
+    # work against padding, per level-loop iteration (read with the
+    # per-level stats; BuildStats sums them)
+    e_cap: int = 0              # edge-buffer rows every iteration runs over
+    aug_cap: int = 0            # augmentation-buffer rows, likewise
+    edges: list = dataclasses.field(default_factory=list)     # n_unique
+    is_edges: list = dataclasses.field(default_factory=list)  # n_is_edges
 
 
 @partial(jax.jit, static_argnames=("n", "d_cap", "aug_cap"))
@@ -176,11 +183,17 @@ def build_hierarchy_device(n: int, src, dst, w, cfg: IndexConfig) -> Hierarchy:
     stays on device across levels in donated buffers; the host reads one
     int32[5] stat vector per level to apply the §5.1 stop rule and the
     capacity checks, then pulls everything once after the loop.
+
+    Spans (``repro.obs.span``): ``islabel.build.peel.upload`` around the
+    edge upload, one ``islabel.build.peel.level`` per ``_peel_step``
+    (attribute ``level``) and ``islabel.build.peel.pull`` around the
+    final read.
     """
     m0 = len(src)
     e_cap = cfg.e_cap(m0)
     aug_cap = cfg.aug_cap(m0)
-    g = gcsr.from_host_edges(src, dst, w, n, e_cap)
+    with span("islabel.build.peel.upload"):
+        g = gcsr.from_host_edges(src, dst, w, n, e_cap)
 
     state = (g.src, g.dst, g.weight, g.via,
              jnp.ones(n, bool),                              # active
@@ -192,18 +205,21 @@ def build_hierarchy_device(n: int, src, dst, w, cfg: IndexConfig) -> Hierarchy:
              jnp.int32(n))                                   # n_verts
 
     graph_sizes = [n + m0 // 2]
-    level_sizes, mis_rounds = [], []
+    level_sizes, mis_rounds, edges, is_edges = [], [], [], []
     k = 1
     peel_iters = 0
-    with hsync.sync_span() as span:
+    with hsync.sync_span() as syncs:
         for i in range(1, cfg.k_max + 1):
             peel_iters = i
-            *state, stats = _peel_step(*state, jnp.int32(i), n,
-                                       cfg.d_cap, aug_cap)
-            # the single blocking transfer of the level: stop-rule scalar
-            # + overflow flags in one int32[5] read
-            n_is, n_unique, n_is_edges, rounds, new_size = (
-                int(x) for x in hsync.host_read(stats))
+            with span("islabel.build.peel.level", level=i):
+                *state, stats = _peel_step(*state, jnp.int32(i), n,
+                                           cfg.d_cap, aug_cap)
+                # the single blocking transfer of the level: stop-rule
+                # scalar + overflow flags in one int32[5] read
+                n_is, n_unique, n_is_edges, rounds, new_size = (
+                    int(x) for x in hsync.host_read(stats))
+            edges.append(n_unique)
+            is_edges.append(n_is_edges)
             if n_unique > e_cap:
                 raise RuntimeError(
                     f"edge capacity overflow at level {i}: {n_unique} > "
@@ -224,14 +240,15 @@ def build_hierarchy_device(n: int, src, dst, w, cfg: IndexConfig) -> Hierarchy:
                     break
             elif new_size > cfg.sigma * graph_sizes[-2]:
                 break
-    loop_syncs = span.count
+    loop_syncs = syncs.count
 
     # one final pull of the whole hierarchy state
     (cur_src, cur_dst, cur_w, cur_via, _active, level_dev,
      up_ids_d, up_w_d, up_via_d, _rng, _nv) = state
-    level, up_ids, up_w, up_via, c_src_p, c_dst_p, c_w_p, c_via_p = (
-        hsync.host_read((level_dev, up_ids_d, up_w_d, up_via_d,
-                         cur_src, cur_dst, cur_w, cur_via)))
+    with span("islabel.build.peel.pull"):
+        level, up_ids, up_w, up_via, c_src_p, c_dst_p, c_w_p, c_via_p = (
+            hsync.host_read((level_dev, up_ids_d, up_w_d, up_via_d,
+                             cur_src, cur_dst, cur_w, cur_via)))
     level = np.array(level)
     level[level == 0] = k
     mask = c_src_p < n
@@ -241,7 +258,8 @@ def build_hierarchy_device(n: int, src, dst, w, cfg: IndexConfig) -> Hierarchy:
                      core_w=c_w_p[mask], core_via=c_via_p[mask],
                      level_sizes=level_sizes, graph_sizes=graph_sizes,
                      mis_rounds=mis_rounds, host_syncs=loop_syncs,
-                     peel_iters=peel_iters)
+                     peel_iters=peel_iters, e_cap=e_cap, aug_cap=aug_cap,
+                     edges=edges, is_edges=is_edges)
 
 
 def build_hierarchy_host(n: int, src, dst, w, cfg: IndexConfig) -> Hierarchy:
@@ -263,10 +281,10 @@ def build_hierarchy_host(n: int, src, dst, w, cfg: IndexConfig) -> Hierarchy:
     n_verts = n
     n_edges = m0
     graph_sizes = [n_verts + n_edges // 2]
-    level_sizes, mis_rounds = [], []
+    level_sizes, mis_rounds, edges, is_edges = [], [], [], []
     k = 1
     peel_iters = 0
-    with hsync.sync_span() as span:
+    with hsync.sync_span() as syncs:
         for i in range(1, cfg.k_max + 1):
             peel_iters = i
             rng, sub = jax.random.split(rng)
@@ -276,12 +294,14 @@ def build_hierarchy_host(n: int, src, dst, w, cfg: IndexConfig) -> Hierarchy:
                 aug_cap)
             n_is_h = int(hsync.host_read(n_is))
             n_unique_h = int(hsync.host_read(n_unique))
+            edges.append(n_unique_h)
             if n_unique_h > e_cap:
                 raise RuntimeError(
                     f"edge capacity overflow at level {i}: "
                     f"{n_unique_h} > {e_cap}; "
                     f"raise IndexConfig.e_cap_factor")
-            if int(hsync.host_read(n_is_edges)) > aug_cap:
+            is_edges.append(int(hsync.host_read(n_is_edges)))
+            if is_edges[-1] > aug_cap:
                 raise RuntimeError(
                     f"augmentation buffer overflow at level {i}; raise "
                     f"aug_cap_factor")
@@ -309,7 +329,7 @@ def build_hierarchy_host(n: int, src, dst, w, cfg: IndexConfig) -> Hierarchy:
                     break
             elif new_size > cfg.sigma * graph_sizes[-2]:
                 break
-    loop_syncs = span.count
+    loop_syncs = syncs.count
 
     level[level == 0] = k
 
@@ -319,7 +339,9 @@ def build_hierarchy_host(n: int, src, dst, w, cfg: IndexConfig) -> Hierarchy:
                      up_via=up_via, core_src=c_src, core_dst=c_dst,
                      core_w=c_w, core_via=c_via, level_sizes=level_sizes,
                      graph_sizes=graph_sizes, mis_rounds=mis_rounds,
-                     host_syncs=loop_syncs, peel_iters=peel_iters)
+                     host_syncs=loop_syncs, peel_iters=peel_iters,
+                     e_cap=e_cap, aug_cap=aug_cap, edges=edges,
+                     is_edges=is_edges)
 
 
 def build_hierarchy(n: int, src, dst, w, cfg: IndexConfig) -> Hierarchy:

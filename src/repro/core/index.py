@@ -10,7 +10,6 @@ from __future__ import annotations
 
 import dataclasses
 import json
-import time
 from pathlib import Path
 
 import jax
@@ -21,6 +20,8 @@ from repro.core.config import BuildStats, IndexConfig
 from repro.core.hierarchy import Hierarchy, build_hierarchy
 from repro.core.labeling import build_labels
 from repro.core.query import QueryEngine
+from repro.obs.profiler import CompileWatcher, compile_region, span
+from repro.obs.registry import MetricRegistry
 
 
 def live_device_bytes() -> int:
@@ -72,21 +73,34 @@ class ISLabelIndex:
     # ------------------------------------------------------------------ build
     @staticmethod
     def build(n, src, dst, w, cfg: IndexConfig = IndexConfig()) -> "ISLabelIndex":
+        """Peel, label, assemble. Each phase is a span (``repro.obs.span``)
+        inside ``islabel.build``: ``islabel.build.peel``,
+        ``islabel.build.label`` (with the wait for the labels) and
+        ``islabel.build.assemble``; their durations are the stats'
+        seconds. Compiles are counted in region ``build``."""
         from repro.core import sync as hsync
-        t0 = time.perf_counter()
         syncs0 = hsync.sync_count()
-        hier = build_hierarchy(n, src, dst, w, cfg)
-        t1 = time.perf_counter()
-        lbl_ids, lbl_d, lbl_pred = build_labels(hier, cfg)
-        jax.block_until_ready(lbl_ids)
-        t2 = time.perf_counter()
-        idx = ISLabelIndex._assemble(n, hier, lbl_ids, lbl_d, lbl_pred, cfg,
-                                     m_input=len(src))
-        idx.stats.build_seconds = time.perf_counter() - t0
-        idx.stats.peel_seconds = t1 - t0
-        idx.stats.label_seconds = t2 - t1
-        idx.stats.host_syncs = hsync.sync_count() - syncs0
-        idx.stats.peak_device_bytes = live_device_bytes()
+        # a private registry: an outer watcher counts these compiles once
+        with span("islabel.build") as whole, \
+                CompileWatcher(MetricRegistry()) as watcher, \
+                compile_region("build"):
+            with span("islabel.build.peel") as peel:
+                hier = build_hierarchy(n, src, dst, w, cfg)
+            with span("islabel.build.label") as label:
+                lbl_ids, lbl_d, lbl_pred = build_labels(hier, cfg)
+                jax.block_until_ready(lbl_ids)
+            with span("islabel.build.assemble") as assemble:
+                idx = ISLabelIndex._assemble(n, hier, lbl_ids, lbl_d,
+                                             lbl_pred, cfg, m_input=len(src))
+        st = idx.stats
+        st.build_seconds = whole.seconds
+        st.peel_seconds = peel.seconds
+        st.label_seconds = label.seconds
+        st.assemble_seconds = assemble.seconds
+        st.compiles = watcher.count("build")
+        st.compile_seconds = watcher.compile_seconds.value(region="build")
+        st.host_syncs = hsync.sync_count() - syncs0
+        st.peak_device_bytes = live_device_bytes()
         return idx
 
     @staticmethod
@@ -106,13 +120,28 @@ class ISLabelIndex:
             backend=cfg.query_backend, query_chunk=cfg.query_chunk,
             label_dtype=cfg.label_dtype)
         ids_h = np.asarray(lbl_ids)
-        entries = int((ids_h[:n] < n).sum())
+        row_len = (ids_h < n).sum(1)           # sentinel row n: 0
+        entries = int(row_len[:n].sum())
+        # the label join's real candidates: a non-core vertex's self entry
+        # and its up-neighbours' labels, final before it is labeled
+        noncore = hier.level < hier.k
+        candidates = int(noncore.sum()
+                         + row_len[hier.up_ids[:n][noncore]].sum())
+        chunk = cfg.label_chunk
+        row_slots = hier.up_ids.shape[1] * cfg.l_cap + 1
         stats = BuildStats(
             n=n, m=m_input, k=hier.k, n_core=n_core,
             m_core=len(hier.core_src), level_sizes=hier.level_sizes,
             graph_sizes=hier.graph_sizes, label_entries=entries,
             label_bytes=entries * 8, mis_rounds=hier.mis_rounds,
-            peel_loop_syncs=hier.host_syncs, peel_iters=hier.peel_iters)
+            peel_loop_syncs=hier.host_syncs, peel_iters=hier.peel_iters,
+            label_candidates=candidates,
+            label_slots=sum(-(-s // chunk) * chunk * row_slots
+                            for s in hier.level_sizes),
+            peel_edges=sum(hier.edges),
+            peel_edge_slots=hier.peel_iters * hier.e_cap,
+            peel_aug_edges=sum(hier.is_edges),
+            peel_aug_slots=hier.peel_iters * hier.aug_cap)
         return ISLabelIndex(
             n=n, k=hier.k, cfg=cfg, level=hier.level, lbl_ids=lbl_ids,
             lbl_d=lbl_d, lbl_pred=lbl_pred, up_ids=hier.up_ids, up_w=hier.up_w,

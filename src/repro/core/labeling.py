@@ -35,6 +35,7 @@ import numpy as np
 from repro.core import sync as hsync
 from repro.core.config import IndexConfig
 from repro.core.hierarchy import Hierarchy
+from repro.obs.profiler import span
 
 
 @partial(jax.jit, static_argnames=("l_cap",),
@@ -109,7 +110,8 @@ def _check_overflow(ovf, cfg: IndexConfig):
     accumulator. Reports the *highest* flagged level — levels are labeled
     k-1 → 1, so that is the first chunk that overflowed chronologically,
     matching the retired eager per-chunk check."""
-    flags = hsync.host_read(ovf)
+    with span("islabel.build.label.check"):
+        flags = hsync.host_read(ovf)
     hit = np.flatnonzero(flags)
     if len(hit):
         raise RuntimeError(
@@ -120,7 +122,12 @@ def _check_overflow(ovf, cfg: IndexConfig):
 def build_labels(hier: Hierarchy, cfg: IndexConfig):
     """Run Algorithm 4 over the hierarchy. Returns device label arrays
     ``(lbl_ids, lbl_d, lbl_pred)``; blocking syncs are limited to the
-    deferred overflow checks (⌈k / sync_every⌉ + 1 total)."""
+    deferred overflow checks (⌈k / sync_every⌉ + 1 total).
+
+    Spans (``repro.obs.span``): one ``islabel.build.label.level`` per
+    level (attributes ``level`` and ``chunks``) around its chunk
+    dispatches, which return before the device has run them, and one
+    ``islabel.build.label.check`` per deferred read."""
     n, k = hier.n, hier.k
     l_cap, chunk = cfg.l_cap, cfg.label_chunk
     sync_every = max(1, cfg.sync_every)
@@ -141,13 +148,15 @@ def build_labels(hier: Hierarchy, cfg: IndexConfig):
     levels_done = 0
     for i in range(k - 1, 0, -1):
         verts = np.flatnonzero(hier.level == i)
-        for lo in range(0, len(verts), chunk):
-            part = verts[lo:lo + chunk]
-            pad = np.full(chunk, n, np.int64)
-            pad[:len(part)] = part
-            lbl_ids, lbl_d, lbl_pred, ovf = label_chunk_step(
-                lbl_ids, lbl_d, lbl_pred, ovf, up_ids, up_w,
-                jnp.asarray(pad, jnp.int32), jnp.int32(i), l_cap)
+        with span("islabel.build.label.level", level=i,
+                  chunks=-(-len(verts) // chunk)):
+            for lo in range(0, len(verts), chunk):
+                part = verts[lo:lo + chunk]
+                pad = np.full(chunk, n, np.int64)
+                pad[:len(part)] = part
+                lbl_ids, lbl_d, lbl_pred, ovf = label_chunk_step(
+                    lbl_ids, lbl_d, lbl_pred, ovf, up_ids, up_w,
+                    jnp.asarray(pad, jnp.int32), jnp.int32(i), l_cap)
         levels_done += 1
         if levels_done % sync_every == 0:
             _check_overflow(ovf, cfg)

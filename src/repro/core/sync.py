@@ -6,11 +6,14 @@ device-resident build (docs/CONSTRUCTION.md) — is *measured*, not
 asserted: ``bench_construction`` snapshots the counter around a build
 and gates ``syncs_per_level <= 1``. ``jax.device_get`` blocks until the
 dependency cone of its operand has executed, so each call counted here
-is one real host stall.
+is one real host stall, and each runs inside an ``islabel.sync`` span:
+in a profile, the device-idle time under it is the stall.
 """
 from __future__ import annotations
 
 import jax
+
+from repro.obs.profiler import span
 
 _COUNT = 0
 
@@ -19,7 +22,8 @@ def host_read(x):
     """Blocking device→host transfer, counted. Returns numpy."""
     global _COUNT
     _COUNT += 1
-    return jax.device_get(x)
+    with span("islabel.sync"):
+        return jax.device_get(x)
 
 
 def sync_count() -> int:

@@ -7,7 +7,8 @@
 from repro.obs.export import EventLog, write_chrome_trace, write_metrics
 from repro.obs.profiler import (CompileWatcher, compile_region,
                                 current_region, device_memory_gauges,
-                                profiler_session, version_family_gauges)
+                                profiler_session, span,
+                                version_family_gauges)
 from repro.obs.registry import (REGISTRY, Counter, Gauge, Histogram,
                                 MetricRegistry, default_latency_buckets)
 from repro.obs.slo import (AlertState, SLOEngine, SLOSpec, compiles_source,
@@ -18,7 +19,8 @@ from repro.obs.trace import NULL_TRACER, NullTracer, Span, Tracer
 __all__ = [
     "EventLog", "write_chrome_trace", "write_metrics",
     "CompileWatcher", "compile_region", "current_region",
-    "device_memory_gauges", "profiler_session", "version_family_gauges",
+    "device_memory_gauges", "profiler_session", "span",
+    "version_family_gauges",
     "REGISTRY", "Counter", "Gauge", "Histogram", "MetricRegistry",
     "default_latency_buckets",
     "AlertState", "SLOEngine", "SLOSpec", "compiles_source",

@@ -1,5 +1,6 @@
 """JAX-level observability: compile-event watching, device-memory /
-live-buffer gauges, and ``jax.profiler`` session wrapping.
+live-buffer gauges, program spans on the profiler's clock (``span``),
+and ``jax.profiler`` session wrapping.
 
 Compile watching turns the serving stack's zero-recompile discipline
 (docs/SERVING.md, docs/MUTATION.md) from a test-time assertion into an
@@ -15,6 +16,8 @@ is equal. The serving engine tags its execution windows with
                which is documented to compile at unwarmed shapes)
   mutation     COW apply / state build (eager scatters may compile
                small executables; never on the read path)
+  build        ``ISLabelIndex.build`` (its own watcher stores the
+               build's compiles in ``BuildStats``)
   other        anything untagged
 
 ``launch/serve.py --mode mutate`` exits nonzero if ``serve_read``
@@ -27,6 +30,7 @@ from __future__ import annotations
 
 import contextlib
 import threading
+import time
 
 import jax
 
@@ -34,7 +38,7 @@ from repro.obs.registry import REGISTRY
 
 __all__ = ["CompileWatcher", "compile_region", "current_region",
            "device_memory_gauges", "version_family_gauges",
-           "profiler_session"]
+           "profiler_session", "span"]
 
 # Duration events jax._src.dispatch emits per XLA backend compile (the
 # jaxpr-trace event fires on cache *misses* at the jit layer too, which
@@ -186,3 +190,31 @@ def profiler_session(log_dir: str | None):
         return
     with jax.profiler.trace(str(log_dir)):
         yield True
+
+
+class span:
+    """A named program span: a ``jax.profiler.TraceAnnotation`` (a host
+    event in the same XSpace, on the same clock, as the device ops when
+    a profiler runs; about a microsecond when none does) that also times
+    itself on ``time.perf_counter``. Always on.
+
+      with span("islabel.build.peel.level", level=i) as sp:
+          ...
+      sp.seconds          # wall time of the block
+    """
+
+    __slots__ = ("_ann", "_t0", "seconds")
+
+    def __init__(self, name: str, **attrs):
+        self._ann = jax.profiler.TraceAnnotation(name, **attrs)
+        self.seconds = 0.0
+
+    def __enter__(self) -> "span":
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        self.seconds = time.perf_counter() - self._t0
+        self._ann.__exit__(*exc)
+        return False

@@ -196,3 +196,112 @@ def test_fixed_seed_build_is_deterministic():
     np.testing.assert_array_equal(np.asarray(a.lbl_ids),
                                   np.asarray(b.lbl_ids))
     np.testing.assert_array_equal(np.asarray(a.lbl_d), np.asarray(b.lbl_d))
+
+
+# ------------------------------------------------- spans and work counters
+
+def _brute_label_candidates(idx):
+    """Σ over non-core v of 1 + Σ_{u in up(v)} |label(u)|, row by row from
+    the host labels and up-edges."""
+    ids = np.asarray(idx.lbl_ids)
+    total = 0
+    for v in range(idx.n):
+        if idx.level[v] == idx.k:
+            continue
+        total += 1
+        for u in idx.up_ids[v]:
+            if u < idx.n:
+                total += int((ids[u] < idx.n).sum())
+    return total
+
+
+@pytest.mark.parametrize("name,mk", GRAPHS)
+def test_build_counters_match_brute_force(name, mk):
+    n, src, dst, w = mk()
+    cfg = IndexConfig(l_cap=256, label_chunk=128)
+    idx = ISLabelIndex.build(n, src, dst, w, cfg)
+    st = idx.stats
+    assert st.label_candidates == _brute_label_candidates(idx)
+    sizes = [int((idx.level == i).sum()) for i in range(1, idx.k)]
+    assert sizes == st.level_sizes
+    row_slots = cfg.d_cap * cfg.l_cap + 1
+    assert st.label_slots == sum(-(-s // 128) * 128 * row_slots
+                                 for s in sizes)
+    assert 0 < st.label_candidates <= st.label_slots
+    m0 = len(src)
+    assert st.peel_edge_slots == st.peel_iters * cfg.e_cap(m0)
+    assert st.peel_aug_slots == st.peel_iters * cfg.aug_cap(m0)
+    assert 0 < st.peel_aug_edges <= st.peel_aug_slots
+    assert 0 < st.peel_edges <= st.peel_edge_slots
+    # the host builder reads the same per-level counts one by one
+    hh = build_hierarchy_host(n, src, dst, w, cfg)
+    assert sum(hh.is_edges) == st.peel_aug_edges
+    assert sum(hh.edges) == st.peel_edges
+    assert hh.peel_iters == st.peel_iters
+
+
+def test_phase_seconds_within_build_seconds():
+    n, src, dst, w = gen.er_graph(300, 3.0, seed=5)
+    cfg = IndexConfig(l_cap=128, label_chunk=64)
+    for _ in range(2):
+        st = ISLabelIndex.build(n, src, dst, w, cfg).stats
+        phases = st.peel_seconds + st.label_seconds + st.assemble_seconds
+        assert min(st.peel_seconds, st.label_seconds,
+                   st.assemble_seconds) > 0
+        assert phases <= st.build_seconds
+    # the second build of the same shapes compiles nothing
+    assert st.compiles == 0 and st.compile_seconds == 0.0
+    assert "assemble" in st.summary() and "fill: label" in st.summary()
+
+
+BUILD_SPANS = {
+    "islabel.build": None,
+    "islabel.build.peel": "islabel.build",
+    "islabel.build.peel.upload": "islabel.build.peel",
+    "islabel.build.peel.level": "islabel.build.peel",
+    "islabel.build.peel.pull": "islabel.build.peel",
+    "islabel.build.label": "islabel.build",
+    "islabel.build.label.level": "islabel.build.label",
+    "islabel.build.label.check": "islabel.build.label",
+    "islabel.build.assemble": "islabel.build",
+}
+
+
+def test_profiled_build_has_nested_spans(tmp_path):
+    """A build under ``jax.profiler`` writes each ``islabel.build*`` span
+    as a host event inside its parent, and one ``islabel.sync`` per
+    counted blocking read."""
+    from jax.profiler import ProfileData
+    n, src, dst, w = gen.er_graph(300, 3.0, seed=5)
+    cfg = IndexConfig(l_cap=128, label_chunk=64)
+    ISLabelIndex.build(n, src, dst, w, cfg)          # compile outside
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        idx = ISLabelIndex.build(n, src, dst, w, cfg)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+    events = {}
+    for plane in ProfileData.from_file(str(path)).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                name = ev.name.split("#")[0]
+                if name.startswith("islabel."):
+                    events.setdefault(name, []).append(
+                        (ev.start_ns, ev.start_ns + ev.duration_ns,
+                         dict(ev.stats)))
+    assert set(BUILD_SPANS) | {"islabel.sync"} <= set(events)
+    assert len(events["islabel.build"]) == 1
+    assert len(events["islabel.build.peel.level"]) == idx.stats.peel_iters
+    assert [e[2]["level"] for e in events["islabel.build.peel.level"]] == \
+        list(range(1, idx.stats.peel_iters + 1))
+    assert len(events["islabel.build.label.level"]) == idx.k - 1
+    for s, e, attrs in events["islabel.build.label.level"]:
+        assert attrs["chunks"] == -(-int((idx.level == attrs["level"]).sum())
+                                    // 64)
+    for child, parent in BUILD_SPANS.items():
+        if parent is None:
+            continue
+        for s, e, _ in events[child]:
+            assert any(ps <= s and e <= pe for ps, pe, _ in events[parent])
+    assert len(events["islabel.sync"]) == idx.stats.host_syncs
