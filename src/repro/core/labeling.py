@@ -6,8 +6,11 @@ every up-neighbor's label is final before it is consumed.
 
 The paper's block-nested-loop join becomes a vectorized *min-plus label
 join*: gather up-neighbor label blocks, add the connecting edge weight,
-then per-row sort by (ancestor id, distance) + first-occurrence compact
-— the fixed-shape analogue of the disk merge. Rows are chunked so the
+then one stable per-row sort keyed on (ancestor id, distance) that
+carries the predecessor along, + first-occurrence compact — the
+fixed-shape analogue of the disk merge. The sort moves the payload with
+its keys, so no per-element gather follows it; what the phase pays for
+is the row width ``d_cap·l_cap + 1``. Rows are chunked so the
 working set stays bounded (the chunk is the VMEM-resident tile of the
 BNL join).
 
@@ -70,15 +73,10 @@ def label_chunk_step(lbl_ids, lbl_d, lbl_pred, ovf, up_ids, up_w, verts,
     d = jnp.where(ids >= n, jnp.inf, d)
     ids = jnp.where(jnp.isinf(d) & (pred >= 0), n, ids)  # drop dead candidates
 
-    # sort rows by (id asc, d asc): stable sort by d, then stable by id
-    o1 = jnp.argsort(d, axis=1, stable=True)
-    ids = jnp.take_along_axis(ids, o1, 1)
-    d = jnp.take_along_axis(d, o1, 1)
-    pred = jnp.take_along_axis(pred, o1, 1)
-    o2 = jnp.argsort(ids, axis=1, stable=True)
-    ids = jnp.take_along_axis(ids, o2, 1)
-    d = jnp.take_along_axis(d, o2, 1)
-    pred = jnp.take_along_axis(pred, o2, 1)
+    # one stable keyed sort per row by (id asc, d asc), pred carried along:
+    # ties of (id, d) keep their input order
+    ids, d, pred = jax.lax.sort((ids, d, pred), dimension=1,
+                                is_stable=True, num_keys=2)
 
     is_first = jnp.concatenate(
         [jnp.ones((c, 1), bool), ids[:, 1:] != ids[:, :-1]], 1) & (ids < n)

@@ -1,5 +1,6 @@
 """Construction-path coverage (docs/CONSTRUCTION.md): capacity-overflow
-semantics, the two-word MIS key, and dual-builder determinism.
+semantics, the two-word MIS key, dual-builder determinism, and the
+label join's keyed sort.
 
 The overflow contract is load-bearing for the deferred-sync design: the
 device builder batches its capacity checks into the per-level stats read
@@ -12,11 +13,12 @@ import numpy as np
 import pytest
 
 import jax
+import jax.numpy as jnp
 
 from repro.core import ISLabelIndex, IndexConfig, build_hierarchy
 from repro.core.hierarchy import (build_hierarchy_device,
                                   build_hierarchy_host)
-from repro.core.labeling import build_labels
+from repro.core.labeling import build_labels, label_chunk_step
 from repro.core.mis import independent_set, lex_less, mis_key_words
 from repro.graphs import generators as gen
 
@@ -305,3 +307,125 @@ def test_profiled_build_has_nested_spans(tmp_path):
         for s, e, _ in events[child]:
             assert any(ps <= s and e <= pe for ps, pe, _ in events[parent])
     assert len(events["islabel.sync"]) == idx.stats.host_syncs
+
+
+# ------------------------------------------------------------ label join
+
+def _two_pass_chunk_step(lbl_ids, lbl_d, lbl_pred, ovf, up_ids, up_w, verts,
+                         lvl, l_cap):
+    """The join as it was before the keyed sort: a stable argsort by d, a
+    stable argsort by id, each followed by three take_along_axis gathers.
+    Everything before and after the sort is ``label_chunk_step``'s."""
+    n = lbl_ids.shape[0] - 1
+    c = verts.shape[0]
+    u = up_ids[verts]
+    w = up_w[verts]
+    d_cap = u.shape[1]
+    cand_ids = lbl_ids[u].reshape(c, d_cap * l_cap)
+    cand_d = (w[:, :, None] + lbl_d[u]).reshape(c, d_cap * l_cap)
+    cand_pred = jnp.broadcast_to(u[:, :, None],
+                                 (c, d_cap, l_cap)).reshape(c, d_cap * l_cap)
+    self_ok = verts < n
+    ids = jnp.concatenate([jnp.where(self_ok, verts, n)[:, None], cand_ids], 1)
+    d = jnp.concatenate([jnp.where(self_ok, 0.0, jnp.inf)[:, None], cand_d], 1)
+    pred = jnp.concatenate([jnp.full((c, 1), -1, jnp.int32), cand_pred], 1)
+    d = jnp.where(ids >= n, jnp.inf, d)
+    ids = jnp.where(jnp.isinf(d) & (pred >= 0), n, ids)
+    for key in ("d", "ids"):
+        o = jnp.argsort(d if key == "d" else ids, axis=1, stable=True)
+        ids, d, pred = (jnp.take_along_axis(a, o, 1) for a in (ids, d, pred))
+    is_first = jnp.concatenate(
+        [jnp.ones((c, 1), bool), ids[:, 1:] != ids[:, :-1]], 1) & (ids < n)
+    posn = jnp.cumsum(is_first.astype(jnp.int32), axis=1) - 1
+    ovf = ovf.at[lvl].max(jnp.any(is_first & (posn >= l_cap)).astype(jnp.int32))
+    col = jnp.where(is_first, jnp.minimum(posn, l_cap), l_cap)
+    ridx = jnp.broadcast_to(jnp.arange(c)[:, None], col.shape)
+    out = []
+    for a, fill, dt in ((ids, n, jnp.int32), (d, jnp.inf, jnp.float32),
+                        (pred, -1, jnp.int32)):
+        rows = jnp.full((c, l_cap + 1), fill, dt)
+        out.append(rows.at[ridx, col].set(jnp.where(is_first, a, fill),
+                                          mode="drop")[:, :l_cap])
+    return (lbl_ids.at[verts].set(out[0]), lbl_d.at[verts].set(out[1]),
+            lbl_pred.at[verts].set(out[2]), ovf)
+
+
+def _join_inputs(case, seed, n=64, d_cap=3, l_cap=12, chunk=16):
+    """One chunk of the label join on a synthetic label table. Ancestor ids
+    come from a small alphabet and distances and weights are small integers,
+    so up-neighbours often offer the same (id, d) under different preds.
+    Only the "overflow" case holds more than ``l_cap`` ancestors in a row:
+    its labels are full and drawn from a wide alphabet."""
+    rng = np.random.default_rng(seed)
+    alphabet = 10 if case != "overflow" else 48
+    lbl_ids = np.full((n + 1, l_cap), n, np.int32)
+    lbl_d = np.full((n + 1, l_cap), np.inf, np.float32)
+    lbl_pred = np.full((n + 1, l_cap), -1, np.int32)
+    for v in range(n):
+        k = l_cap if case == "overflow" else int(rng.integers(1, 7))
+        lbl_ids[v, :k] = np.sort(rng.choice(alphabet, k, replace=False))
+        lbl_d[v, :k] = rng.integers(0, 3, k)
+        lbl_pred[v, :k] = rng.integers(-1, n, k)
+    up_ids = rng.integers(0, n, (n + 1, d_cap)).astype(np.int32)
+    up_w = rng.integers(1, 3, (n + 1, d_cap)).astype(np.float32)
+    up_ids[n], up_w[n] = n, np.inf
+    if case == "dead":
+        # padded up-slots, unreachable up-edges and unreachable label entries
+        up_ids[rng.random(up_ids.shape) < 0.3] = n
+        up_w[rng.random(up_w.shape) < 0.2] = np.inf
+        lbl_d[rng.random(lbl_d.shape) < 0.2] = np.inf
+    verts = rng.choice(np.arange(alphabet, n), chunk, replace=False)
+    if case == "padded":
+        verts[chunk // 2:] = n
+    ovf = np.zeros(4, np.int32)
+    return [jnp.asarray(a) for a in (lbl_ids, lbl_d, lbl_pred, ovf, up_ids,
+                                     up_w, verts.astype(np.int32))], l_cap
+
+
+@pytest.mark.parametrize("case,seed", [("ties", 0), ("ties", 1),
+                                       ("dead", 2), ("padded", 3),
+                                       ("overflow", 4)])
+def test_label_join_bitwise_equals_two_pass_sort(case, seed):
+    args, l_cap = _join_inputs(case, seed)
+    lvl = jnp.int32(2)
+    want = jax.jit(_two_pass_chunk_step, static_argnames=("l_cap",))(
+        *args, lvl, l_cap=l_cap)
+    got = label_chunk_step(*[jnp.array(a, copy=True) for a in args], lvl,
+                           l_cap)
+    for g, e in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(e))
+        assert np.asarray(g).tobytes() == np.asarray(e).tobytes()
+    assert int(np.asarray(got[3])[2]) == (case == "overflow")
+    if case == "ties":
+        # the inputs hold what the case is for: a row that offers one
+        # (id, d) under two different preds
+        lbl_ids, lbl_d, _, _, up_ids, up_w, verts = map(np.asarray, args)
+        u = up_ids[verts]
+        ids = lbl_ids[u]
+        d = up_w[verts][:, :, None] + lbl_d[u]
+        row = np.broadcast_to(np.arange(len(verts))[:, None, None], ids.shape)
+        pred = np.broadcast_to(u[:, :, None], ids.shape)
+        ok = (ids < lbl_ids.shape[0] - 1) & np.isfinite(d)
+        cands = np.unique(np.stack([row[ok], ids[ok], d[ok], pred[ok]], 1),
+                          axis=0)
+        assert len(np.unique(cands[:, :3], axis=0)) < len(cands)
+
+
+def test_label_join_has_no_candidate_tile_gather():
+    """The join sorts its candidate tile with the payload, so no gather
+    in the lowered program yields a [chunk, d_cap*l_cap + 1] array."""
+    n, d_cap, l_cap, chunk = 40, 3, 5, 8
+    width = d_cap * l_cap + 1
+    args = (jnp.zeros((n + 1, l_cap), jnp.int32),
+            jnp.zeros((n + 1, l_cap), jnp.float32),
+            jnp.zeros((n + 1, l_cap), jnp.int32),
+            jnp.zeros(4, jnp.int32),
+            jnp.zeros((n + 1, d_cap), jnp.int32),
+            jnp.zeros((n + 1, d_cap), jnp.float32),
+            jnp.zeros(chunk, jnp.int32), jnp.int32(1))
+    text = label_chunk_step.lower(*args, l_cap).as_text()
+    gathers = [ln for ln in text.splitlines() if "gather" in ln]
+    assert gathers, "the up-neighbour label gathers should remain"
+    tile = f"tensor<{chunk}x{width}x"
+    assert not [ln for ln in gathers if f"-> {tile}" in ln]
+    assert "stablehlo.sort" in text
