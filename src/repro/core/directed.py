@@ -7,101 +7,30 @@ u -> v -> w through a removed v. Two label families per vertex:
 over in-ancestors; a query (s, t) intersects out(s) with in(t) and the
 core search relaxes forward from s-seeds and backward from t-seeds.
 
-Implementation: the in-label machinery is exactly the out-label
-machinery on the reversed graph, so build_labels is reused verbatim with
-a reversed Hierarchy view. This module also answers *reachability*
-(dist < inf), the paper's closing claim.
+Implementation: the undirected build and query paths with a direction.
+The peel is ``build_hierarchy_device`` with ``directed=True`` (one
+``_peel_step`` program, one blocking read per level), which records the
+out- and in-adjacency up-edges; the in-label machinery is exactly the
+out-label machinery on the reversed up-edges, so ``build_labels`` runs
+once per family; queries go through ``QueryEngine`` with the in-labels
+as the target side's planes and the reversed core for its stage 2. This
+module also answers *reachability* (dist < inf), the paper's closing
+claim.
 """
 from __future__ import annotations
 
 import dataclasses
-from functools import partial
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.config import IndexConfig
-from repro.core.hierarchy import Hierarchy
+from repro.core.config import BuildStats, IndexConfig
+from repro.core.hierarchy import build_hierarchy_device
+from repro.core.index import build_stats, core_positions, timed_build
 from repro.core.labeling import build_labels
-from repro.core.mis import independent_set
-from repro.core.query import core_relax, label_intersect_mu
-from repro.graphs import csr as gcsr
-from repro.graphs import segment_ops as sops
-
-
-@partial(jax.jit, static_argnames=("n", "d_cap", "aug_cap"))
-def peel_level_directed(src, dst, w, via, active, rng, n: int, d_cap: int,
-                        aug_cap: int):
-    """One directed hierarchy level. Degree/eligibility use the union
-    (in+out) adjacency; augmenting pairs are IN(v) x OUT(v)."""
-    e_cap = src.shape[0]
-    valid = src < n
-    # symmetrized view for the MIS (independence ignores direction)
-    sym_src = jnp.concatenate([src, dst])
-    sym_dst = jnp.concatenate([dst, src])
-    sym_valid = jnp.concatenate([valid, valid])
-    in_is, rounds = independent_set(sym_src, sym_dst, sym_valid, active,
-                                    rng, n, d_cap)
-
-    g_fwd = gcsr.EdgeList(src, dst, w, via, n_nodes=n)
-    g_bwd = gcsr.EdgeList(dst, src, w, via, n_nodes=n)
-    out_ids, out_w, out_via, _ = gcsr.neighbor_matrix(g_fwd, d_cap)
-    in_ids, in_w, in_via, _ = gcsr.neighbor_matrix(g_bwd, d_cap)
-
-    # edges OUT of IS vertices: (v -> u); pair with v's IN neighbors
-    is_out = in_is[jnp.where(valid, src, 0)] & valid
-    pos = jnp.cumsum(is_out.astype(jnp.int32)) - 1
-    tgt = jnp.where(is_out & (pos < aug_cap), pos, aug_cap)
-
-    def compact(vals, fill):
-        buf = jnp.full((aug_cap + 1,), fill, vals.dtype)
-        return buf.at[tgt].set(jnp.where(is_out, vals, fill),
-                               mode="drop")[:aug_cap]
-
-    a_v = compact(src, n)
-    a_u = compact(dst, n)
-    a_w = compact(w, jnp.inf)
-    n_is_edges = jnp.sum(is_out.astype(jnp.int32))
-
-    p_ids = in_ids[a_v]                       # in-neighbors of v [aug, d]
-    p_w = in_w[a_v]
-    pair_ok = (p_ids < n) & (p_ids != a_u[:, None]) & (a_u[:, None] < n)
-    pair_src = jnp.where(pair_ok, p_ids, n)                      # win -> u
-    pair_dst = jnp.where(pair_ok,
-                         jnp.broadcast_to(a_u[:, None], p_ids.shape), n)
-    pair_w = jnp.where(pair_ok, p_w + a_w[:, None], jnp.inf)
-    pair_via = jnp.where(pair_ok,
-                         jnp.broadcast_to(a_v[:, None], p_ids.shape), -1)
-
-    drop = in_is[jnp.where(valid, src, 0)] | in_is[jnp.where(valid, dst, 0)]
-    keep = valid & ~drop
-    all_src = jnp.concatenate([jnp.where(keep, src, n), pair_src.reshape(-1)])
-    all_dst = jnp.concatenate([jnp.where(keep, dst, n), pair_dst.reshape(-1)])
-    all_w = jnp.concatenate([jnp.where(keep, w, jnp.inf), pair_w.reshape(-1)])
-    all_via = jnp.concatenate([jnp.where(keep, via, -1),
-                               pair_via.reshape(-1)])
-    o_src, o_dst, o_w, o_via, n_unique = gcsr.dedup_min_edges(
-        all_src, all_dst, all_w, all_via, n, e_cap)
-    n_is = jnp.sum(in_is.astype(jnp.int32))
-    return (o_src, o_dst, o_w, o_via, in_is, out_ids, out_w, out_via,
-            in_ids, in_w, in_via, n_unique, n_is, n_is_edges, rounds)
-
-
-@partial(jax.jit, static_argnames=("n_core",))
-def _relax_one(seed, es, ed, ew, n_core: int):
-    """One-directional Bellman-Ford on the (possibly reversed) core."""
-    def body(state):
-        d, it, _ = state
-        d2 = d.at[:, ed].min(d[:, es] + ew[None, :])
-        return d2, it + 1, jnp.any(d2 < d)
-
-    def cond(state):
-        return state[2] & (state[1] < n_core)
-
-    d, _, _ = jax.lax.while_loop(
-        cond, body, (seed, jnp.int32(0), jnp.bool_(True)))
-    return d
+from repro.core.query import QueryEngine
+from repro.obs.profiler import span
 
 
 @dataclasses.dataclass
@@ -113,8 +42,9 @@ class DiISLabelIndex:
     out_lbl: tuple      # (ids, d, pred) device arrays (out-ancestors)
     in_lbl: tuple
     core_pos: np.ndarray
-    core_edges: tuple   # fwd local (src, dst, w)
     n_core: int
+    engine: QueryEngine
+    stats: BuildStats
     # host state for §8.1/§8.2 path reconstruction: the out/in
     # up-adjacency matrices ((ids, w, via) triples) and the core COO in
     # global ids with its via bookkeeping
@@ -131,103 +61,60 @@ class DiISLabelIndex:
 
     @staticmethod
     def build(n, src, dst, w, cfg: IndexConfig = IndexConfig()):
-        # no key-width guard: the MIS compares (deg, perm) as two words
-        # (core/mis.py), so million-vertex builds need no uint32 budget
-        m0 = len(src)
-        e_cap, aug_cap = cfg.e_cap(m0), cfg.aug_cap(m0)
-        g = gcsr.from_host_edges(src, dst, w, n, e_cap)
-        rng = jax.random.PRNGKey(cfg.seed)
-        level = np.zeros(n, np.int32)
-        ups = {d: (np.full((n + 1, cfg.d_cap), n, np.int32),
-                   np.full((n + 1, cfg.d_cap), np.inf, np.float32),
-                   np.full((n + 1, cfg.d_cap), -1, np.int32))
-               for d in ("out", "in")}
-        active = jnp.ones(n, bool)
-        cs, cd, cw, cv = g.src, g.dst, g.weight, g.via
-        sizes = [n + m0]
-        k = 1
-        for i in range(1, cfg.k_max + 1):
-            rng, sub = jax.random.split(rng)
-            (o_src, o_dst, o_w, o_via, in_is, out_ids, out_w, out_via,
-             in_ids, in_w, in_via, n_unique, n_is, n_is_e, _) = \
-                peel_level_directed(cs, cd, cw, cv, active, sub, n,
-                                    cfg.d_cap, aug_cap)
-            if int(n_unique) > e_cap or int(n_is_e) > aug_cap:
-                raise RuntimeError("capacity overflow; raise e_cap_factor")
-            if int(n_is) == 0:
-                k = i
-                break
-            mask = np.asarray(in_is)
-            level[mask] = i
-            for key_, (ids_a, w_a, via_a) in zip(
-                    ("out", "in"),
-                    ((out_ids, out_w, out_via), (in_ids, in_w, in_via))):
-                ups[key_][0][:n][mask] = np.asarray(ids_a)[:n][mask]
-                ups[key_][1][:n][mask] = np.asarray(w_a)[:n][mask]
-                ups[key_][2][:n][mask] = np.asarray(via_a)[:n][mask]
-            active = active & ~in_is
-            cs, cd, cw, cv = o_src, o_dst, o_w, o_via
-            k = i + 1
-            new_size = int((np.asarray(cs) < n).sum()) + n - int(level.astype(bool).sum())
-            sizes.append(new_size)
-            if cfg.k_force:
-                if k >= cfg.k_force:
-                    break
-            elif new_size > cfg.sigma * sizes[-2]:
-                break
-        level[level == 0] = k
+        """Peel, label both families, assemble: the arcs ``src -> dst``.
 
-        ce_s, ce_d, ce_w, ce_v = gcsr.to_host_coo(
-            gcsr.EdgeList(cs, cd, cw, cv, n_nodes=n))
+        The spans are ``ISLabelIndex.build``'s (``timed_build``), with
+        ``islabel.build.label.out`` and ``islabel.build.label.in`` inside
+        ``islabel.build.label``, one per ``build_labels`` call; each ends
+        in its blocking overflow read. The peel always runs the device
+        level loop (``IndexConfig.builder`` picks between the undirected
+        loops only)."""
+        def label(hier):
+            up_in = dataclasses.replace(hier, up_ids=hier.up_in[0],
+                                        up_w=hier.up_in[1],
+                                        up_via=hier.up_in[2])
+            lbls = []
+            for family, h in (("out", hier), ("in", up_in)):
+                with span(f"islabel.build.label.{family}"):
+                    lbls.append(build_labels(h, cfg))
+                    jax.block_until_ready(lbls[-1][0])
+            return lbls
 
-        def labels_for(direction):
-            hier = Hierarchy(
-                n=n, k=k, level=level, up_ids=ups[direction][0],
-                up_w=ups[direction][1], up_via=ups[direction][2],
-                core_src=ce_s, core_dst=ce_d, core_w=ce_w,
-                core_via=np.zeros_like(ce_s), level_sizes=[],
-                graph_sizes=[], mis_rounds=[])
-            return build_labels(hier, cfg)
+        return timed_build(
+            lambda: build_hierarchy_device(n, src, dst, w, cfg,
+                                           directed=True),
+            label,
+            lambda hier, lbl: DiISLabelIndex._assemble(n, hier, *lbl, cfg,
+                                                       m_input=len(src)))
 
-        out_lbl = labels_for("out")
-        in_lbl = labels_for("in")
-        core_ids = np.flatnonzero(level == k).astype(np.int32)
-        core_pos = np.full(n + 1, len(core_ids), np.int32)
-        core_pos[core_ids] = np.arange(len(core_ids), dtype=np.int32)
+    @staticmethod
+    def _assemble(n, hier, out_lbl, in_lbl, cfg: IndexConfig,
+                  m_input: int) -> "DiISLabelIndex":
+        core_ids, core_pos = core_positions(n, hier)
+        n_core = len(core_ids)
+        ce_s, ce_d = (jnp.asarray(core_pos[a])
+                      for a in (hier.core_src, hier.core_dst))
+        ce_w = jnp.asarray(hier.core_w, jnp.float32)
+        engine = QueryEngine(
+            out_lbl[0], out_lbl[1], jnp.asarray(core_pos), (ce_s, ce_d, ce_w),
+            n=n, n_core=n_core, max_rounds=cfg.max_relax_rounds,
+            backend=cfg.query_backend, query_chunk=cfg.query_chunk,
+            label_dtype=cfg.label_dtype, t_labels=in_lbl[:2],
+            t_core_edges=(ce_d, ce_s, ce_w))
+        stats = build_stats(n, m_input, hier, cfg,
+                            [(np.asarray(out_lbl[0]), hier.up_ids),
+                             (np.asarray(in_lbl[0]), hier.up_in[0])])
         return DiISLabelIndex(
-            n=n, k=k, cfg=cfg, level=level, out_lbl=out_lbl, in_lbl=in_lbl,
-            core_pos=core_pos,
-            core_edges=(jnp.asarray(core_pos[ce_s]),
-                        jnp.asarray(core_pos[ce_d]), jnp.asarray(ce_w)),
-            n_core=len(core_ids),
-            up_out=ups["out"], up_in=ups["in"],
-            core_host=(ce_s, ce_d, ce_w, ce_v))
+            n=n, k=hier.k, cfg=cfg, level=hier.level, out_lbl=out_lbl,
+            in_lbl=in_lbl, core_pos=core_pos, n_core=n_core, engine=engine,
+            stats=stats, up_out=(hier.up_ids, hier.up_w, hier.up_via),
+            up_in=hier.up_in,
+            core_host=(hier.core_src, hier.core_dst, hier.core_w,
+                       hier.core_via))
 
     def query(self, s, t):
         """Directed distances dist(s -> t), batched."""
-        s = jnp.asarray(s, jnp.int32)
-        t = jnp.asarray(t, jnp.int32)
-        ids_s, d_s = self.out_lbl[0][s], self.out_lbl[1][s]
-        ids_t, d_t = self.in_lbl[0][t], self.in_lbl[1][t]
-        mu, _ = label_intersect_mu(ids_s, d_s, ids_t, d_t, self.n,
-                                   ids_s.shape[1])
-        if self.n_core == 0:
-            return mu
-        cpos = jnp.asarray(self.core_pos)
-        q = s.shape[0]
-        ridx = jnp.broadcast_to(jnp.arange(q)[:, None], ids_s.shape)
-        seed_s = jnp.full((q, self.n_core + 1), jnp.inf, jnp.float32).at[
-            ridx, cpos[jnp.minimum(ids_s, self.n)]].min(
-            jnp.where(ids_s < self.n, d_s, jnp.inf))
-        seed_t = jnp.full((q, self.n_core + 1), jnp.inf, jnp.float32).at[
-            ridx, cpos[jnp.minimum(ids_t, self.n)]].min(
-            jnp.where(ids_t < self.n, d_t, jnp.inf))
-        es, ed, ew = self.core_edges
-        # forward relax for DS; DT relaxes on the reversed core graph
-        ds = _relax_one(seed_s, es, ed, ew, self.n_core)
-        dt = _relax_one(seed_t, ed, es, ew, self.n_core)
-        through = jnp.min(ds[:, :self.n_core] + dt[:, :self.n_core], axis=1)
-        return jnp.minimum(mu, through)
+        return self.engine.query(s, t)
 
     def query_host(self, s, t):
         return np.asarray(self.query(np.atleast_1d(s), np.atleast_1d(t)))

@@ -29,6 +29,13 @@ stages of Algorithm 1 route through here:
                   frontier. Mosaic gathers only within one vreg, so no
                   Pallas form of this step lowers at these sizes.
 
+A directed core (paper §8.2) relaxes the target side over the reversed
+core: the reference route scatters it over the reversed arcs, and the
+kernel routes relax both frontiers side by side, as one row per query,
+over the block-diagonal union of the core and its reverse (``directed``
+layout of ``_stack``/``_finish``). An undirected core keeps the stacked
+rows over the one graph.
+
 Every route computes the same per-round fixed point (synchronous Jacobi
 Bellman-Ford over G_k), so answers agree bitwise: each round takes a min
 over the identical multiset of candidate sums regardless of whether the
@@ -102,20 +109,25 @@ def label_intersect_rows_dispatch(rows_s: LabelRows, rows_t: LabelRows,
 
 @partial(jax.jit, static_argnames=("n_core", "max_rounds"))
 def core_relax(seed_s, seed_t, ce_src, ce_dst, ce_w, mu,
-               n_core: int, max_rounds: int):
+               n_core: int, max_rounds: int, t_edges=None):
     """Reference bidirectional label-seeded relaxation on G_k (Alg. 1
     stage 2) — COO scatter-min wavefront rounds.
 
     seed_s/seed_t: [Q, n_core+1] initial distance vectors (+inf default,
     label distances scattered in, sentinel column n_core).
+    ``t_edges`` (src, dst, w): the arcs the target side relaxes over
+    (the reversed core of a directed index); None: the same arcs.
     Returns (ans [Q], ds, dt, rounds) with ans = min(μ, min_v ds+dt).
     """
+    t_src, t_dst, t_w = (ce_src, ce_dst, ce_w) if t_edges is None \
+        else t_edges
+
     def body(state):
         ds, dt, it, _ = state
         cs = ds[:, ce_src] + ce_w[None, :]
         ds2 = ds.at[:, ce_dst].min(cs)
-        ct = dt[:, ce_src] + ce_w[None, :]
-        dt2 = dt.at[:, ce_dst].min(ct)
+        ct = dt[:, t_src] + t_w[None, :]
+        dt2 = dt.at[:, t_dst].min(ct)
         improved = jnp.any(ds2 < ds) | jnp.any(dt2 < dt)
         return ds2, dt2, it + 1, improved
 
@@ -132,18 +144,22 @@ def core_relax(seed_s, seed_t, ce_src, ce_dst, ce_w, mu,
         return jnp.minimum(mu, through_core), ds, dt, rounds
 
 
-def _stack(seed_s, seed_t, rows_to: int, cols_to: int):
-    """Both frontiers stacked into one [2Q, V] matrix, +inf padded."""
-    d0 = jnp.concatenate([seed_s, seed_t], axis=0)
+def _stack(seed_s, seed_t, cols_to: int, directed: bool, row_mult: int = 1):
+    """Both frontiers in one matrix, +inf padded to ``cols_to`` columns
+    and a multiple of ``row_mult`` rows: stacked as rows, [2Q, V], over
+    one graph; side by side, [Q, 2V], over a directed core's
+    block-diagonal union with its reverse."""
+    d0 = jnp.concatenate([seed_s, seed_t], axis=1 if directed else 0)
     rows, v = d0.shape
+    rows_to = -(-rows // row_mult) * row_mult
     return jnp.pad(d0, ((0, rows_to - rows), (0, cols_to - v)),
                    constant_values=jnp.inf)
 
 
-def _finish(d, mu, q: int, v: int, n_core: int):
-    """Unstack [2Q(+pad), V(+pad)] and meet the two frontiers."""
+def _finish(d, mu, q: int, v: int, n_core: int, directed: bool):
+    """Unstack ``_stack``'s layout (padded) and meet the two frontiers."""
     ds = d[:q, :v]
-    dt = d[q:2 * q, :v]
+    dt = d[:q, v:2 * v] if directed else d[q:2 * q, :v]
     through_core = jnp.min(ds[:, :n_core] + dt[:, :n_core], axis=1)
     return jnp.minimum(mu, through_core), ds, dt
 
@@ -157,15 +173,15 @@ def ell_round(d, nbr_ids_t, nbr_w_t):
     return jax.lax.fori_loop(0, nbr_ids_t.shape[0], slot, d)
 
 
-@partial(jax.jit, static_argnames=("n_core", "max_rounds"))
+@partial(jax.jit, static_argnames=("n_core", "max_rounds", "directed"))
 def _core_relax_ell(seed_s, seed_t, nbr_ids, nbr_w, mu, n_core: int,
-                    max_rounds: int):
+                    max_rounds: int, directed: bool = False):
     """XLA relaxation: both frontiers stacked vertex-major into one
-    [Vp, 2Q] matrix (a relaxation step gathers whole rows), one
-    ``ell_round`` per ``lax.while_loop`` step."""
+    [Vp, 2Q] matrix ([2Vp, Q] directed; a relaxation step gathers whole
+    rows), one ``ell_round`` per ``lax.while_loop`` step."""
     q, v = seed_s.shape
     vp = nbr_ids.shape[0]
-    d0 = _stack(seed_s, seed_t, 2 * q, vp).T
+    d0 = _stack(seed_s, seed_t, vp, directed).T
     ids_t, w_t = nbr_ids.T, nbr_w.T
 
     def body(state):
@@ -180,39 +196,41 @@ def _core_relax_ell(seed_s, seed_t, nbr_ids, nbr_w, mu, n_core: int,
     with jax.named_scope("islabel.core_relax_ell"):
         d, rounds, _ = jax.lax.while_loop(
             cond, body, (d0, jnp.int32(0), jnp.bool_(True)))
-        return (*_finish(d.T, mu, q, v, n_core), rounds)
+        return (*_finish(d.T, mu, q, v, n_core, directed), rounds)
 
 
-@partial(jax.jit,
-         static_argnames=("n_core", "max_rounds", "interpret", "bq"))
+@partial(jax.jit, static_argnames=("n_core", "max_rounds", "interpret",
+                                   "bq", "directed"))
 def _core_relax_fused(seed_s, seed_t, nbr_ids, nbr_w, mu, n_core: int,
-                      max_rounds: int, interpret: bool, bq: int):
+                      max_rounds: int, interpret: bool, bq: int,
+                      directed: bool = False):
     """Fused relaxation: both frontiers stacked, ALL rounds in one
     ``fused_relax_kernel`` launch with the fixed-point exit in-kernel.
     Batch rounds = max over per-block rounds (all-pad blocks settle in
     one round, real blocks freeze bitwise at their own fixed point)."""
     q, v = seed_s.shape
     vp = nbr_ids.shape[0]
-    d0 = _stack(seed_s, seed_t, -(-2 * q // bq) * bq, vp)
+    d0 = _stack(seed_s, seed_t, vp, directed, bq)
 
     with jax.named_scope("islabel.core_relax_fused"):
         d, blk_rounds = fused_relax_kernel(
             d0, nbr_ids.T, nbr_w.T, max_rounds=max_rounds, bq=bq,
             interpret=interpret)
         rounds = jnp.max(blk_rounds, initial=0).astype(jnp.int32)
-        return (*_finish(d, mu, q, v, n_core), rounds)
+        return (*_finish(d, mu, q, v, n_core, directed), rounds)
 
 
-@partial(jax.jit,
-         static_argnames=("n_core", "max_rounds", "interpret", "bm"))
+@partial(jax.jit, static_argnames=("n_core", "max_rounds", "interpret",
+                                   "bm", "directed"))
 def _core_relax_dense(seed_s, seed_t, adj, mu, n_core: int,
-                      max_rounds: int, interpret: bool, bm: int = 8):
+                      max_rounds: int, interpret: bool, bm: int = 8,
+                      directed: bool = False):
     """Dense-core relaxation: one ``minplus_matmul`` tropical GEMM per
     round against the 0-diagonal adjacency (the diagonal supplies the
     keep-old term, so ``minplus(d, adj)`` IS the synchronous round)."""
     q, v = seed_s.shape
     vp = adj.shape[0]
-    d0 = _stack(seed_s, seed_t, -(-2 * q // bm) * bm, vp)
+    d0 = _stack(seed_s, seed_t, vp, directed, bm)
 
     def body(state):
         d, it, _ = state
@@ -226,7 +244,7 @@ def _core_relax_dense(seed_s, seed_t, adj, mu, n_core: int,
     with jax.named_scope("islabel.core_relax_dense"):
         d, rounds, _ = jax.lax.while_loop(
             cond, body, (d0, jnp.int32(0), jnp.bool_(True)))
-        return (*_finish(d, mu, q, v, n_core), rounds)
+        return (*_finish(d, mu, q, v, n_core, directed), rounds)
 
 
 class CoreRelaxer:
@@ -245,9 +263,15 @@ class CoreRelaxer:
     ``fused_fits`` (small core, working set inside the VMEM budget);
     else "ell_xla". Set env ``ISLABEL_FUSED_RELAX=0`` to skip the fused
     kernel.
+
+    ``rev`` (src, dst, w), local indices: the arcs the target side
+    relaxes over, the reversed core of a directed index. None (an
+    undirected core): both sides relax over the same arcs. With ``rev``
+    the kernel layouts are of the block-diagonal union of the core
+    (vertices [0, V)) and ``rev`` (vertices [V, 2V)), V = n_core + 1.
     """
 
-    def __init__(self, ce_src, ce_dst, ce_w, n_core: int, *,
+    def __init__(self, ce_src, ce_dst, ce_w, n_core: int, *, rev=None,
                  bq: int = 8, d_width: int = 16,
                  fused: bool | None = None,
                  dense_threshold: float | None = None,
@@ -257,6 +281,7 @@ class CoreRelaxer:
         self.ce_dst = ce_dst
         self.ce_w = ce_w
         self.n_core = n_core
+        self.rev = rev
         self.bq = bq
         self.d_width = d_width
         if fused is None:
@@ -290,20 +315,30 @@ class CoreRelaxer:
                 self._mode = "ell_xla"
         return self._mode
 
+    def _graph(self):
+        """(vertex count, src, dst, w) on the host of the graph the
+        kernel routes relax over: the core, or with ``rev`` the
+        block-diagonal union of the core and ``rev``."""
+        v = self.n_core + 1
+        src, dst, w = (np.asarray(a) for a in
+                       (self.ce_src, self.ce_dst, self.ce_w))
+        if self.rev is None:
+            return v, src, dst, w
+        r_src, r_dst, r_w = (np.asarray(a) for a in self.rev)
+        return (2 * v, np.concatenate([src, r_src + v]),
+                np.concatenate([dst, r_dst + v]), np.concatenate([w, r_w]))
+
     def dense_adj(self):
         """[Vp, Vp] float32 dense adjacency: adj[src, dst] = min edge
         weight (parallel edges dedup exactly — fp add is monotone in w),
         +inf elsewhere, diagonal min'd with 0 on ALL rows including the
         sentinel and lane padding so parked values survive each round."""
         if self._adj is None:
-            v = self.n_core + 1
+            v, src, dst, w = self._graph()
             vp = -(-v // LANES) * LANES
             adj = np.full((vp, vp), np.inf, np.float32)
-            src = np.asarray(self.ce_src)
-            dst = np.asarray(self.ce_dst)
             if len(src):
-                np.minimum.at(adj, (src, dst),
-                              np.asarray(self.ce_w, np.float32))
+                np.minimum.at(adj, (src, dst), np.asarray(w, np.float32))
             idx = np.arange(vp)
             adj[idx, idx] = np.minimum(adj[idx, idx], 0.0)
             # lazily built, possibly first reached inside a jit /
@@ -314,17 +349,14 @@ class CoreRelaxer:
         return self._adj
 
     def ell(self):
-        """(nbr_ids [Vp, D], nbr_w [Vp, D]) with Vp = n_core+1 rounded up
-        to a multiple of 128 (sentinel column included, padding rows
-        edgeless)."""
+        """(nbr_ids [Vp, D], nbr_w [Vp, D]) with Vp = n_core+1 (2·(n_core+1)
+        with ``rev``) rounded up to a multiple of 128 (sentinel columns
+        included, padding rows edgeless)."""
         if self._ell is None:
-            v = self.n_core + 1
+            v, src, dst, w = self._graph()
             vp = -(-v // LANES) * LANES
             with jax.ensure_compile_time_eval():
-                ids, ws = coo_to_ell(v, np.asarray(self.ce_src),
-                                     np.asarray(self.ce_dst),
-                                     np.asarray(self.ce_w),
-                                     d_width=self.d_width)
+                ids, ws = coo_to_ell(v, src, dst, w, d_width=self.d_width)
                 ids = jnp.pad(ids, ((0, vp - v), (0, 0)))
                 ws = jnp.pad(ws, ((0, vp - v), (0, 0)),
                              constant_values=jnp.inf)
@@ -337,17 +369,19 @@ class CoreRelaxer:
         backend = resolve_backend(backend)
         if backend == "reference":
             return core_relax(seed_s, seed_t, self.ce_src, self.ce_dst,
-                              self.ce_w, mu, self.n_core, max_rounds)
+                              self.ce_w, mu, self.n_core, max_rounds,
+                              self.rev)
         interpret = pallas_interpret(backend)
         mode = self.mode
+        directed = self.rev is not None
         if mode == "dense":
             return _core_relax_dense(seed_s, seed_t, self.dense_adj(), mu,
                                      self.n_core, max_rounds, interpret,
-                                     self.bq)
+                                     self.bq, directed)
         nbr_ids, nbr_w = self.ell()
         if mode == "fused":
             return _core_relax_fused(seed_s, seed_t, nbr_ids, nbr_w, mu,
                                      self.n_core, max_rounds, interpret,
-                                     self.bq)
+                                     self.bq, directed)
         return _core_relax_ell(seed_s, seed_t, nbr_ids, nbr_w, mu,
-                               self.n_core, max_rounds)
+                               self.n_core, max_rounds, directed)

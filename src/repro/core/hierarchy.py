@@ -19,6 +19,13 @@ the overflow checks (the overflow flags ride the same transfer, so the
 check costs no extra sync and still raises with the offending level).
 Level/up-edge/core arrays come back to host in one final pull.
 
+A directed graph (paper §8.2) runs the same step under the static
+``directed`` flag: the MIS runs on the symmetrised view (independence
+ignores direction), an augmenting arc (p, u) is made for every 2-path
+p -> v -> u through a removed v (IN(v) x OUT(v)), and both v's out- and
+in-adjacency are recorded as up-edges. The undirected program is
+unchanged by the flag.
+
 ``build_hierarchy_host`` is the original loop — one ``peel_level`` call
 per level with per-level scalar syncs and full neighbor-matrix round
 trips through numpy. It is kept as the reference the construction bench
@@ -58,6 +65,9 @@ class Hierarchy:
     level_sizes: list
     graph_sizes: list
     mis_rounds: list
+    # a directed build's in-adjacency up-edges (ids, w, via), laid out
+    # as up_*; up_* then hold the out-adjacency. None when undirected.
+    up_in: tuple | None = None
     host_syncs: int = 0         # blocking device→host reads in the level loop
     peel_iters: int = 0         # level-loop iterations (peel_level calls) —
                                 # the bench gate is host_syncs <= peel_iters
@@ -69,20 +79,39 @@ class Hierarchy:
     is_edges: list = dataclasses.field(default_factory=list)  # n_is_edges
 
 
-@partial(jax.jit, static_argnames=("n", "d_cap", "aug_cap"))
-def peel_level(src, dst, w, via, active, rng, n: int, d_cap: int, aug_cap: int):
+@partial(jax.jit, static_argnames=("n", "d_cap", "aug_cap", "directed"))
+def peel_level(src, dst, w, via, active, rng, n: int, d_cap: int, aug_cap: int,
+               directed: bool = False):
     """One hierarchy level. Returns the new edge list + bookkeeping.
 
     All arrays fixed-shape; counters returned for host-side overflow
-    checks. e_cap is implied by src.shape.
+    checks. e_cap is implied by src.shape. ``nbrs`` holds one
+    ``(ids, w, via)`` neighbour matrix per up-edge family: the adjacency
+    (undirected), or the out- then the in-adjacency (``directed``).
     """
     e_cap = src.shape[0]
     valid = src < n
-    in_is, rounds = independent_set(src, dst, valid, active, rng, n, d_cap)
+    if directed:
+        # independence ignores direction: the MIS runs on the
+        # symmetrised view (degree = in + out)
+        in_is, rounds = independent_set(
+            jnp.concatenate([src, dst]), jnp.concatenate([dst, src]),
+            jnp.concatenate([valid, valid]), active, rng, n, d_cap)
+    else:
+        in_is, rounds = independent_set(src, dst, valid, active, rng, n,
+                                        d_cap)
 
     # --- ADJ(L_i): neighbor matrix rows of IS vertices --------------------
     nbr_ids, nbr_w, nbr_via, _ = gcsr.neighbor_matrix(
         gcsr.EdgeList(src, dst, w, via, n_nodes=n), d_cap)
+    nbrs = ((nbr_ids, nbr_w, nbr_via),)
+    # partners of an IS vertex v: its neighbours, or its in-neighbours
+    part_ids, part_w = nbr_ids, nbr_w
+    if directed:
+        in_ids, in_w, in_via, _ = gcsr.neighbor_matrix(
+            gcsr.EdgeList(dst, src, w, via, n_nodes=n), d_cap)
+        nbrs += ((in_ids, in_w, in_via),)
+        part_ids, part_w = in_ids, in_w
 
     # --- compact IS-incident edges into the augmentation buffer -----------
     is_src = in_is[jnp.where(valid, src, 0)] & valid   # edge (v,u), v in L_i
@@ -97,12 +126,15 @@ def peel_level(src, dst, w, via, active, rng, n: int, d_cap: int, aug_cap: int):
     n_is_edges = jnp.sum(is_src.astype(jnp.int32))
 
     # --- augmenting pairs: (u, partner) for each partner slot of v --------
-    # a_* rows: edge (v, u); partners = nbr rows of v
-    p_ids = nbr_ids[a_v]                    # [aug_cap, d_cap]
-    p_w = nbr_w[a_v]
+    # a_* rows: edge (v, u); partners = nbr rows of v. Directed: the
+    # partner p is an in-neighbour and the pair is the arc p -> u.
+    p_ids = part_ids[a_v]                   # [aug_cap, d_cap]
+    p_w = part_w[a_v]
     pair_ok = (p_ids < n) & (p_ids != a_u[:, None]) & (a_u[:, None] < n)
-    pair_src = jnp.where(pair_ok, jnp.broadcast_to(a_u[:, None], p_ids.shape), n)
-    pair_dst = jnp.where(pair_ok, p_ids, n)
+    u_b = jnp.broadcast_to(a_u[:, None], p_ids.shape)
+    head, tail = (p_ids, u_b) if directed else (u_b, p_ids)
+    pair_src = jnp.where(pair_ok, head, n)
+    pair_dst = jnp.where(pair_ok, tail, n)
     pair_w = jnp.where(pair_ok, a_w[:, None] + p_w, jnp.inf)
     pair_via = jnp.where(pair_ok, jnp.broadcast_to(a_v[:, None], p_ids.shape), -1)
 
@@ -123,22 +155,26 @@ def peel_level(src, dst, w, via, active, rng, n: int, d_cap: int, aug_cap: int):
         all_src, all_dst, all_w, all_via, n, e_cap)
 
     n_is = jnp.sum(in_is.astype(jnp.int32))
-    return (o_src, o_dst, o_w, o_via, in_is, nbr_ids, nbr_w, nbr_via,
+    return (o_src, o_dst, o_w, o_via, in_is, nbrs,
             n_unique, n_is, n_is_edges, rounds)
 
 
-@partial(jax.jit, static_argnames=("n", "d_cap", "aug_cap"),
+@partial(jax.jit, static_argnames=("n", "d_cap", "aug_cap", "directed"),
          donate_argnames=("src", "dst", "w", "via", "active", "level_dev",
-                          "up_ids", "up_w", "up_via"))
+                          "up_ids", "up_w", "up_via", "up_in"))
 def _peel_step(src, dst, w, via, active, level_dev, up_ids, up_w, up_via,
-               rng, n_verts, lvl, n: int, d_cap: int, aug_cap: int):
+               rng, n_verts, lvl, n: int, d_cap: int, aug_cap: int,
+               directed: bool = False, up_in=()):
     """One device-resident hierarchy level.
 
     Runs ``peel_level`` and folds the host-side bookkeeping of the
     original loop into the same jitted call: level recording and up-edge
     recording under the IS mask, active-set update, and the running
-    ``|V|+|E|/2`` size for the stop rule. ``lvl`` and ``n_verts`` are
-    traced scalars so the call compiles once per (n, d_cap, aug_cap).
+    ``|V|+|E|/2`` size for the stop rule (``|V|+|E|`` over arcs when
+    ``directed``). ``lvl`` and ``n_verts`` are traced scalars so the call
+    compiles once per (n, d_cap, aug_cap). ``directed`` records the
+    out-adjacency in ``up_*`` and the in-adjacency in ``up_in``, an
+    ``(ids, w, via)`` triple returned last (empty when undirected).
 
     Returns the updated state plus ``stats`` int32[5] =
     ``[n_is, n_unique, n_is_edges, mis_rounds, new_size]`` — the one
@@ -148,18 +184,21 @@ def _peel_step(src, dst, w, via, active, level_dev, up_ids, up_w, up_via,
     that breaks before recording).
     """
     rng, sub = jax.random.split(rng)
-    (o_src, o_dst, o_w, o_via, in_is, nbr_ids, nbr_w, nbr_via,
+    (o_src, o_dst, o_w, o_via, in_is, nbrs,
      n_unique, n_is, n_is_edges, rounds) = peel_level(
-        src, dst, w, via, active, sub, n, d_cap, aug_cap)
+        src, dst, w, via, active, sub, n, d_cap, aug_cap, directed)
 
     has_is = n_is > 0
     # record level + up-edges under the IS mask (row n of up_* is the
     # sentinel row — the mask is False there by construction)
     rec = jnp.concatenate([in_is, jnp.zeros((1,), bool)])
     level_dev = jnp.where(in_is, lvl.astype(jnp.int32), level_dev)
-    up_ids = jnp.where(rec[:, None], nbr_ids, up_ids)
-    up_w = jnp.where(rec[:, None], nbr_w, up_w)
-    up_via = jnp.where(rec[:, None], nbr_via, up_via)
+    up_ids = jnp.where(rec[:, None], nbrs[0][0], up_ids)
+    up_w = jnp.where(rec[:, None], nbrs[0][1], up_w)
+    up_via = jnp.where(rec[:, None], nbrs[0][2], up_via)
+    if directed:
+        up_in = tuple(jnp.where(rec[:, None], new, old)
+                      for new, old in zip(nbrs[1], up_in))
     active = active & ~in_is
     # keep the pre-step edge list when the IS is empty: that graph IS the
     # core (dedup of an already-deduped list is value-identical, but the
@@ -170,19 +209,22 @@ def _peel_step(src, dst, w, via, active, level_dev, up_ids, up_w, up_via,
     via = jnp.where(has_is, o_via, via)
 
     n_verts = n_verts - n_is
-    new_size = n_verts + n_unique // 2
+    new_size = n_verts + (n_unique if directed else n_unique // 2)
     stats = jnp.stack([n_is, n_unique, n_is_edges, rounds, new_size])
     return (src, dst, w, via, active, level_dev, up_ids, up_w, up_via,
-            rng, n_verts, stats)
+            rng, n_verts, stats, up_in)
 
 
-def build_hierarchy_device(n: int, src, dst, w, cfg: IndexConfig) -> Hierarchy:
+def build_hierarchy_device(n: int, src, dst, w, cfg: IndexConfig,
+                           directed: bool = False) -> Hierarchy:
     """Device-resident level loop: one blocking host sync per level.
 
     All state (edge list, active set, level assignment, up-edge matrix)
     stays on device across levels in donated buffers; the host reads one
     int32[5] stat vector per level to apply the §5.1 stop rule and the
     capacity checks, then pulls everything once after the loop.
+    ``directed`` takes ``src -> dst`` as arcs and also records the
+    in-adjacency up-edges (``Hierarchy.up_in``), in the same pull.
 
     Spans (``repro.obs.span``): ``islabel.build.peel.upload`` around the
     edge upload, one ``islabel.build.peel.level`` per ``_peel_step``
@@ -195,16 +237,20 @@ def build_hierarchy_device(n: int, src, dst, w, cfg: IndexConfig) -> Hierarchy:
     with span("islabel.build.peel.upload"):
         g = gcsr.from_host_edges(src, dst, w, n, e_cap)
 
+    def up_family():
+        return (jnp.full((n + 1, cfg.d_cap), n, jnp.int32),    # ids
+                jnp.full((n + 1, cfg.d_cap), jnp.inf, jnp.float32),
+                jnp.full((n + 1, cfg.d_cap), -1, jnp.int32))
+
     state = (g.src, g.dst, g.weight, g.via,
              jnp.ones(n, bool),                              # active
              jnp.zeros(n, jnp.int32),                        # level
-             jnp.full((n + 1, cfg.d_cap), n, jnp.int32),     # up_ids
-             jnp.full((n + 1, cfg.d_cap), jnp.inf, jnp.float32),
-             jnp.full((n + 1, cfg.d_cap), -1, jnp.int32),
+             *up_family(),                                   # up_*
              jax.random.PRNGKey(cfg.seed),
              jnp.int32(n))                                   # n_verts
+    up_in = up_family() if directed else ()
 
-    graph_sizes = [n + m0 // 2]
+    graph_sizes = [n + (m0 if directed else m0 // 2)]
     level_sizes, mis_rounds, edges, is_edges = [], [], [], []
     k = 1
     peel_iters = 0
@@ -212,8 +258,9 @@ def build_hierarchy_device(n: int, src, dst, w, cfg: IndexConfig) -> Hierarchy:
         for i in range(1, cfg.k_max + 1):
             peel_iters = i
             with span("islabel.build.peel.level", level=i):
-                *state, stats = _peel_step(*state, jnp.int32(i), n,
-                                           cfg.d_cap, aug_cap)
+                *state, stats, up_in = _peel_step(
+                    *state, jnp.int32(i), n, cfg.d_cap, aug_cap, directed,
+                    up_in)
                 # the single blocking transfer of the level: stop-rule
                 # scalar + overflow flags in one int32[5] read
                 n_is, n_unique, n_is_edges, rounds, new_size = (
@@ -226,8 +273,9 @@ def build_hierarchy_device(n: int, src, dst, w, cfg: IndexConfig) -> Hierarchy:
                     f"{e_cap}; raise IndexConfig.e_cap_factor")
             if n_is_edges > aug_cap:
                 raise RuntimeError(
-                    f"augmentation buffer overflow at level {i}; raise "
-                    f"aug_cap_factor")
+                    f"augmentation buffer overflow at level {i}: "
+                    f"{n_is_edges} > {aug_cap}; raise "
+                    f"IndexConfig.aug_cap_factor")
             if n_is == 0:
                 k = i
                 break
@@ -246,14 +294,15 @@ def build_hierarchy_device(n: int, src, dst, w, cfg: IndexConfig) -> Hierarchy:
     (cur_src, cur_dst, cur_w, cur_via, _active, level_dev,
      up_ids_d, up_w_d, up_via_d, _rng, _nv) = state
     with span("islabel.build.peel.pull"):
-        level, up_ids, up_w, up_via, c_src_p, c_dst_p, c_w_p, c_via_p = (
-            hsync.host_read((level_dev, up_ids_d, up_w_d, up_via_d,
-                             cur_src, cur_dst, cur_w, cur_via)))
+        (level, up_ids, up_w, up_via, c_src_p, c_dst_p, c_w_p, c_via_p,
+         up_in) = hsync.host_read((level_dev, up_ids_d, up_w_d, up_via_d,
+                                   cur_src, cur_dst, cur_w, cur_via, up_in))
     level = np.array(level)
     level[level == 0] = k
     mask = c_src_p < n
     return Hierarchy(n=n, k=k, level=level, up_ids=np.array(up_ids),
                      up_w=np.array(up_w), up_via=np.array(up_via),
+                     up_in=tuple(np.array(a) for a in up_in) or None,
                      core_src=c_src_p[mask], core_dst=c_dst_p[mask],
                      core_w=c_w_p[mask], core_via=c_via_p[mask],
                      level_sizes=level_sizes, graph_sizes=graph_sizes,
@@ -288,7 +337,7 @@ def build_hierarchy_host(n: int, src, dst, w, cfg: IndexConfig) -> Hierarchy:
         for i in range(1, cfg.k_max + 1):
             peel_iters = i
             rng, sub = jax.random.split(rng)
-            (o_src, o_dst, o_w, o_via, in_is, nbr_ids, nbr_w, nbr_via,
+            (o_src, o_dst, o_w, o_via, in_is, ((nbr_ids, nbr_w, nbr_via),),
              n_unique, n_is, n_is_edges, rounds) = peel_level(
                 cur_src, cur_dst, cur_w, cur_via, active, sub, n, cfg.d_cap,
                 aug_cap)
@@ -303,8 +352,9 @@ def build_hierarchy_host(n: int, src, dst, w, cfg: IndexConfig) -> Hierarchy:
             is_edges.append(int(hsync.host_read(n_is_edges)))
             if is_edges[-1] > aug_cap:
                 raise RuntimeError(
-                    f"augmentation buffer overflow at level {i}; raise "
-                    f"aug_cap_factor")
+                    f"augmentation buffer overflow at level {i}: "
+                    f"{is_edges[-1]} > {aug_cap}; raise "
+                    f"IndexConfig.aug_cap_factor")
             if n_is_h == 0:
                 k = i
                 break

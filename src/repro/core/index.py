@@ -37,6 +37,79 @@ def live_device_bytes() -> int:
     return int(sum(x.nbytes for x in jax.live_arrays()))
 
 
+def timed_build(peel, label, assemble):
+    """Run a build's phases, each a span (``repro.obs.span``) inside
+    ``islabel.build``: ``islabel.build.peel`` (``peel() -> hier``),
+    ``islabel.build.label`` (``label(hier) -> labels``, which waits for
+    the labels) and ``islabel.build.assemble`` (``assemble(hier, labels)
+    -> index``). Fills the index's ``BuildStats`` with the spans'
+    durations, the compiles counted in region ``build`` and the blocking
+    reads."""
+    from repro.core import sync as hsync
+    syncs0 = hsync.sync_count()
+    # a private registry: an outer watcher counts these compiles once
+    with span("islabel.build") as whole, \
+            CompileWatcher(MetricRegistry()) as watcher, \
+            compile_region("build"):
+        with span("islabel.build.peel") as peel_span:
+            hier = peel()
+        with span("islabel.build.label") as label_span:
+            labels = label(hier)
+        with span("islabel.build.assemble") as assemble_span:
+            idx = assemble(hier, labels)
+    st = idx.stats
+    st.build_seconds = whole.seconds
+    st.peel_seconds = peel_span.seconds
+    st.label_seconds = label_span.seconds
+    st.assemble_seconds = assemble_span.seconds
+    st.compiles = watcher.count("build")
+    st.compile_seconds = watcher.compile_seconds.value(region="build")
+    st.host_syncs = hsync.sync_count() - syncs0
+    st.peak_device_bytes = live_device_bytes()
+    return idx
+
+
+def core_positions(n: int, hier):
+    """``(core_ids int32[n_core], core_pos int32[n+1])``: the core's
+    vertices and each vertex's local core index (``n_core`` off it), from
+    anything with the hierarchy's ``level`` and ``k``."""
+    core_ids = np.flatnonzero(hier.level == hier.k).astype(np.int32)
+    core_pos = np.full(n + 1, len(core_ids), np.int32)
+    core_pos[core_ids] = np.arange(len(core_ids), dtype=np.int32)
+    return core_ids, core_pos
+
+
+def build_stats(n: int, m_input: int, hier: Hierarchy, cfg: IndexConfig,
+                families) -> BuildStats:
+    """The counters of one build; label counts summed over ``families``,
+    ``[(label ids on the host, the up-edge ids they were joined over)]``
+    (one family undirected, out and in directed)."""
+    noncore = hier.level < hier.k
+    chunk = cfg.label_chunk
+    row_slots = hier.up_ids.shape[1] * cfg.l_cap + 1
+    entries = candidates = 0
+    for ids_h, up_ids in families:
+        row_len = (ids_h < n).sum(1)           # sentinel row n: 0
+        entries += int(row_len[:n].sum())
+        # the label join's real candidates: a non-core vertex's self
+        # entry and its up-neighbours' labels, final before it is labeled
+        candidates += int(noncore.sum()
+                          + row_len[up_ids[:n][noncore]].sum())
+    return BuildStats(
+        n=n, m=m_input, k=hier.k, n_core=int((~noncore).sum()),
+        m_core=len(hier.core_src), level_sizes=hier.level_sizes,
+        graph_sizes=hier.graph_sizes, label_entries=entries,
+        label_bytes=entries * 8, mis_rounds=hier.mis_rounds,
+        peel_loop_syncs=hier.host_syncs, peel_iters=hier.peel_iters,
+        label_candidates=candidates,
+        label_slots=len(families) * sum(-(-s // chunk) * chunk * row_slots
+                                        for s in hier.level_sizes),
+        peel_edges=sum(hier.edges),
+        peel_edge_slots=hier.peel_iters * hier.e_cap,
+        peel_aug_edges=sum(hier.is_edges),
+        peel_aug_slots=hier.peel_iters * hier.aug_cap)
+
+
 @dataclasses.dataclass
 class ISLabelIndex:
     n: int
@@ -73,43 +146,22 @@ class ISLabelIndex:
     # ------------------------------------------------------------------ build
     @staticmethod
     def build(n, src, dst, w, cfg: IndexConfig = IndexConfig()) -> "ISLabelIndex":
-        """Peel, label, assemble. Each phase is a span (``repro.obs.span``)
-        inside ``islabel.build``: ``islabel.build.peel``,
-        ``islabel.build.label`` (with the wait for the labels) and
-        ``islabel.build.assemble``; their durations are the stats'
-        seconds. Compiles are counted in region ``build``."""
-        from repro.core import sync as hsync
-        syncs0 = hsync.sync_count()
-        # a private registry: an outer watcher counts these compiles once
-        with span("islabel.build") as whole, \
-                CompileWatcher(MetricRegistry()) as watcher, \
-                compile_region("build"):
-            with span("islabel.build.peel") as peel:
-                hier = build_hierarchy(n, src, dst, w, cfg)
-            with span("islabel.build.label") as label:
-                lbl_ids, lbl_d, lbl_pred = build_labels(hier, cfg)
-                jax.block_until_ready(lbl_ids)
-            with span("islabel.build.assemble") as assemble:
-                idx = ISLabelIndex._assemble(n, hier, lbl_ids, lbl_d,
-                                             lbl_pred, cfg, m_input=len(src))
-        st = idx.stats
-        st.build_seconds = whole.seconds
-        st.peel_seconds = peel.seconds
-        st.label_seconds = label.seconds
-        st.assemble_seconds = assemble.seconds
-        st.compiles = watcher.count("build")
-        st.compile_seconds = watcher.compile_seconds.value(region="build")
-        st.host_syncs = hsync.sync_count() - syncs0
-        st.peak_device_bytes = live_device_bytes()
-        return idx
+        """Peel, label, assemble, timed by ``timed_build``."""
+        def label(hier):
+            lbl = build_labels(hier, cfg)
+            jax.block_until_ready(lbl[0])
+            return lbl
+
+        return timed_build(
+            lambda: build_hierarchy(n, src, dst, w, cfg), label,
+            lambda hier, lbl: ISLabelIndex._assemble(n, hier, *lbl, cfg,
+                                                     m_input=len(src)))
 
     @staticmethod
     def _assemble(n, hier: Hierarchy, lbl_ids, lbl_d, lbl_pred,
                   cfg: IndexConfig, m_input: int) -> "ISLabelIndex":
-        core_ids = np.flatnonzero(hier.level == hier.k).astype(np.int32)
+        core_ids, core_pos = core_positions(n, hier)
         n_core = len(core_ids)
-        core_pos = np.full(n + 1, n_core, np.int32)
-        core_pos[core_ids] = np.arange(n_core, dtype=np.int32)
         ce_src = core_pos[hier.core_src]
         ce_dst = core_pos[hier.core_dst]
         engine = QueryEngine(
@@ -119,29 +171,8 @@ class ISLabelIndex:
             n=n, n_core=n_core, max_rounds=cfg.max_relax_rounds,
             backend=cfg.query_backend, query_chunk=cfg.query_chunk,
             label_dtype=cfg.label_dtype)
-        ids_h = np.asarray(lbl_ids)
-        row_len = (ids_h < n).sum(1)           # sentinel row n: 0
-        entries = int(row_len[:n].sum())
-        # the label join's real candidates: a non-core vertex's self entry
-        # and its up-neighbours' labels, final before it is labeled
-        noncore = hier.level < hier.k
-        candidates = int(noncore.sum()
-                         + row_len[hier.up_ids[:n][noncore]].sum())
-        chunk = cfg.label_chunk
-        row_slots = hier.up_ids.shape[1] * cfg.l_cap + 1
-        stats = BuildStats(
-            n=n, m=m_input, k=hier.k, n_core=n_core,
-            m_core=len(hier.core_src), level_sizes=hier.level_sizes,
-            graph_sizes=hier.graph_sizes, label_entries=entries,
-            label_bytes=entries * 8, mis_rounds=hier.mis_rounds,
-            peel_loop_syncs=hier.host_syncs, peel_iters=hier.peel_iters,
-            label_candidates=candidates,
-            label_slots=sum(-(-s // chunk) * chunk * row_slots
-                            for s in hier.level_sizes),
-            peel_edges=sum(hier.edges),
-            peel_edge_slots=hier.peel_iters * hier.e_cap,
-            peel_aug_edges=sum(hier.is_edges),
-            peel_aug_slots=hier.peel_iters * hier.aug_cap)
+        stats = build_stats(n, m_input, hier, cfg,
+                            [(np.asarray(lbl_ids), hier.up_ids)])
         return ISLabelIndex(
             n=n, k=hier.k, cfg=cfg, level=hier.level, lbl_ids=lbl_ids,
             lbl_d=lbl_d, lbl_pred=lbl_pred, up_ids=hier.up_ids, up_w=hier.up_w,
@@ -345,10 +376,8 @@ class ISLabelIndex:
         self._host_labels = host
         self._core_adj = None
         self._paths = None
-        core_ids = np.flatnonzero(self.level == self.k).astype(np.int32)
+        core_ids, core_pos = core_positions(self.n, self)
         n_core = len(core_ids)
-        core_pos = np.full(self.n + 1, n_core, np.int32)
-        core_pos[core_ids] = np.arange(n_core, dtype=np.int32)
         self.core_ids, self.core_pos_host = core_ids, core_pos
         self.engine = QueryEngine(
             self.lbl_ids, self.lbl_d, jnp.asarray(core_pos),

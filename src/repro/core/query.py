@@ -74,11 +74,18 @@ class QueryEngine:
     fit; "auto" compresses when possible and silently keeps fp32
     otherwise. Serving gathers the compressed planes directly; decode is
     fused into the intersect kernel and the stage-2 seed scatter.
+
+    A directed index (paper §8.2) gives the target side its own label
+    planes, ``t_labels`` = (in-label ids, d), and the reversed core,
+    ``t_core_edges`` (local src, dst, w), which stage 2 relaxes the
+    t-seeds over. None (undirected): the source side's planes and core
+    serve both sides.
     """
 
     def __init__(self, lbl_ids, lbl_d, core_pos, core_local_edges, n: int,
                  n_core: int, max_rounds: int = 0, backend: str = "auto",
-                 query_chunk: int = 0, label_dtype: str = "fp32"):
+                 query_chunk: int = 0, label_dtype: str = "fp32",
+                 t_labels=None, t_core_edges=None):
         self.lbl_ids = lbl_ids
         self.lbl_d = lbl_d
         self.core_pos = core_pos              # int32[n+1] -> [0..n_core]
@@ -93,29 +100,33 @@ class QueryEngine:
             raise ValueError(f"unknown label_dtype {label_dtype!r}")
         self.label_dtype = label_dtype
         self.codec = "none"
-        self.enc_ids, self.enc_base, self.enc_d = lbl_ids, None, lbl_d
+        planes = [(lbl_ids, None, lbl_d)]
+        if t_labels is not None:
+            planes.append((t_labels[0], None, t_labels[1]))
         if label_dtype != "fp32":
             encode = (encode_labels if label_dtype == "compressed"
                       else try_encode_labels)
-            enc = encode(np.asarray(lbl_ids), np.asarray(lbl_d), n)
-            if enc is not None:
-                delta, base, denc = enc
+            encs = [encode(np.asarray(ids), np.asarray(d), n)
+                    for ids, _, d in planes]
+            if all(enc is not None for enc in encs):
                 self.codec = "delta16"
-                self.enc_ids = jnp.asarray(delta)
-                self.enc_base = jnp.asarray(base)
-                self.enc_d = jnp.asarray(denc)
+                planes = [tuple(jnp.asarray(a) for a in enc) for enc in encs]
+        # (ids, base, d) of the source side, then of the target side
+        self.planes = (planes[0], planes[-1])
+        self.enc_ids, self.enc_base, self.enc_d = planes[0]
         self.relaxer = CoreRelaxer(self.ce_src, self.ce_dst, self.ce_w,
-                                   n_core) if n_core > 0 else None
+                                   n_core, rev=t_core_edges) \
+            if n_core > 0 else None
         self._last_rounds = 0
         self._batch_fns: dict = {}     # backend -> jitted serving callable
         self._mu_batch_fns: dict = {}
 
-    def _rows(self, idx) -> LabelRows:
-        """Gather label rows for a vertex batch in the active codec."""
-        if self.codec == "none":
-            return LabelRows(self.lbl_ids[idx], None, self.lbl_d[idx])
-        return LabelRows(self.enc_ids[idx], self.enc_base[idx],
-                         self.enc_d[idx])
+    def _rows(self, idx, side: int = 0) -> LabelRows:
+        """Gather label rows for a vertex batch in the active codec, from
+        the source (``side`` 0) or the target (1) planes."""
+        ids, base, d = self.planes[side]
+        return LabelRows(ids[idx], None if base is None else base[idx],
+                         d[idx])
 
     def _seed(self, ids, d):
         q = ids.shape[0]
@@ -129,7 +140,7 @@ class QueryEngine:
         rounds) with rounds a device scalar (None when there is no
         core) — callers reduce it lazily so chunked batches never sync
         to host between launches."""
-        rows_s, rows_t = self._rows(s), self._rows(t)
+        rows_s, rows_t = self._rows(s), self._rows(t, 1)
         mu = label_intersect_rows_dispatch(rows_s, rows_t, self.n,
                                            self.codec, backend)
         if self.n_core == 0:
@@ -174,7 +185,7 @@ class QueryEngine:
         s = jnp.asarray(s, jnp.int32)
         t = jnp.asarray(t, jnp.int32)
         backend = resolve_backend(self.backend if backend is None else backend)
-        return label_intersect_rows_dispatch(self._rows(s), self._rows(t),
+        return label_intersect_rows_dispatch(self._rows(s), self._rows(t, 1),
                                              self.n, self.codec, backend)
 
     def classify(self, s, t, level, k):
@@ -216,7 +227,7 @@ class QueryEngine:
         if backend not in self._mu_batch_fns:
             def run(s, t):
                 return label_intersect_rows_dispatch(
-                    self._rows(s), self._rows(t), self.n, self.codec,
+                    self._rows(s), self._rows(t, 1), self.n, self.codec,
                     backend)
             self._mu_batch_fns[backend] = jax.jit(run)
         return self._mu_batch_fns[backend]

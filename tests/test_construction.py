@@ -429,3 +429,51 @@ def test_label_join_has_no_candidate_tile_gather():
     tile = f"tensor<{chunk}x{width}x"
     assert not [ln for ln in gathers if f"-> {tile}" in ln]
     assert "stablehlo.sort" in text
+
+
+# ------------------------------------------------- pinned build programs
+
+# sha256 of the lowered (StableHLO) text of the undirected build's two
+# device programs at a fixed small shape. Both build cells of the
+# benchmark run these programs; a change to either is measured there
+# before its digest is updated here.
+PINNED_PROGRAMS = {
+    "peel_step": "197261cc95422e2410e42ac8cc3e2e8d94eb9ed2902594441753d979f20fe4d2",
+    "label_chunk_step":
+        "5e52cbd7dbd636ea9c5f1e825efc7cd2ef9be658c8477f3d65a2c44829ff40c2",
+}
+
+
+def _lowered_text(program: str, **static) -> str:
+    from repro.core.hierarchy import _peel_step
+    n, e_cap, d_cap, aug_cap, l_cap, chunk = 64, 256, 8, 128, 16, 32
+    i32, f32 = jnp.int32, jnp.float32
+    sds = jax.ShapeDtypeStruct
+    if program == "peel_step":
+        up = (sds((n + 1, d_cap), i32), sds((n + 1, d_cap), f32),
+              sds((n + 1, d_cap), i32))
+        args = (sds((e_cap,), i32), sds((e_cap,), i32), sds((e_cap,), f32),
+                sds((e_cap,), i32), sds((n,), bool), sds((n,), i32), *up,
+                jax.random.PRNGKey(0), sds((), i32), sds((), i32))
+        if static.get("directed"):
+            static["up_in"] = up
+        return _peel_step.lower(*args, n=n, d_cap=d_cap, aug_cap=aug_cap,
+                                **static).as_text()
+    return label_chunk_step.lower(
+        sds((n + 1, l_cap), i32), sds((n + 1, l_cap), f32),
+        sds((n + 1, l_cap), i32), sds((5,), i32), sds((n + 1, d_cap), i32),
+        sds((n + 1, d_cap), f32), sds((chunk,), i32), sds((), i32),
+        l_cap=l_cap).as_text()
+
+
+@pytest.mark.parametrize("program", sorted(PINNED_PROGRAMS))
+def test_undirected_build_programs_are_pinned(program):
+    """The directed flag adds nothing to the undirected peel step, and
+    the label join both families share is the one the build cells run."""
+    import hashlib
+    text = _lowered_text(program)
+    assert hashlib.sha256(text.encode()).hexdigest() == \
+        PINNED_PROGRAMS[program]
+    if program == "peel_step":
+        assert _lowered_text(program, directed=False) == text
+        assert _lowered_text(program, directed=True) != text
