@@ -18,7 +18,9 @@ class IndexConfig:
     d_cap: int = 16            # IS eligibility degree cap (paper: greedy
                                # min-degree; we peel only deg<=d_cap vertices)
     e_cap_factor: float = 2.0  # edge capacity = factor * initial |E|
-    aug_cap_factor: float = 1.0  # IS-incident edge buffer = factor * |E|
+    aug_cap_factor: float = 1.0  # ceiling of the IS-incident edge buffer
+                                 # = factor * |E| (each level's buffer is
+                                 # a power-of-two bucket of its bound)
     builder: str = "device"    # level loop: device (sync-free, one stat
                                # read per level) | host (reference loop;
                                # bitwise-equal, docs/CONSTRUCTION.md)
@@ -80,7 +82,7 @@ class BuildStats:
     peel_edges: int = 0             # Σ deduped edges over level iterations
     peel_edge_slots: int = 0        # peel_iters · e_cap
     peel_aug_edges: int = 0         # Σ IS-incident edges (augmentation)
-    peel_aug_slots: int = 0         # peel_iters · aug_cap
+    peel_aug_slots: int = 0         # Σ per-iteration aug_cap
 
     def summary(self) -> str:
         def pct(a, b):

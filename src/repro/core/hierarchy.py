@@ -12,11 +12,13 @@ Two builders share the level loop semantics (docs/CONSTRUCTION.md):
 ``build_hierarchy_device`` (default) keeps every buffer device-resident
 across levels: level assignment and up-edge recording happen inside the
 jitted ``_peel_step`` (donated buffers, masked ``where`` under the IS
-mask), and the only blocking host transfer per level is one int32[5]
+mask), and the only blocking host transfer per level is one int32[6]
 stat vector — IS size, deduped edge count, augmentation fill, MIS
-rounds, next graph size — from which the host applies the stop rule and
-the overflow checks (the overflow flags ride the same transfer, so the
-check costs no extra sync and still raises with the offending level).
+rounds, next graph size, next eligible-degree bound — from which the
+host applies the stop rule and the overflow checks (the overflow flags
+ride the same transfer, so the check costs no extra sync and still
+raises with the offending level) and sizes the next level's
+augmentation buffer.
 Level/up-edge/core arrays come back to host in one final pull.
 
 A directed graph (paper §8.2) runs the same step under the static
@@ -44,6 +46,7 @@ from repro.core import sync as hsync
 from repro.core.config import IndexConfig
 from repro.core.mis import independent_set
 from repro.graphs import csr as gcsr
+from repro.graphs import segment_ops as sops
 from repro.obs.profiler import span
 
 
@@ -74,9 +77,44 @@ class Hierarchy:
     # work against padding, per level-loop iteration (read with the
     # per-level stats; BuildStats sums them)
     e_cap: int = 0              # edge-buffer rows every iteration runs over
-    aug_cap: int = 0            # augmentation-buffer rows, likewise
+    # augmentation-buffer rows of each iteration (``level_aug_cap``)
+    aug_caps: list = dataclasses.field(default_factory=list)
     edges: list = dataclasses.field(default_factory=list)     # n_unique
     is_edges: list = dataclasses.field(default_factory=list)  # n_is_edges
+
+
+# The augmentation buffer holds the IS-incident edges (v, u), v in L_i.
+# L_i lies inside the eligible set (active, degree <= d_cap; mis.py), so
+# their count never exceeds B = the out-degree sum over eligible
+# vertices (degree = in + out when directed). Each level's buffer is B
+# rounded up to a power of two, so a level compiles one of few shapes,
+# under the ceiling ``cfg.aug_cap(m0)``, the only cap that can overflow.
+
+def level_aug_cap(bound: int, ceiling: int) -> int:
+    """Augmentation-buffer rows for a level whose eligible-degree bound
+    is ``bound``: ``min(ceiling, max(64, next_pow2(bound)))``."""
+    return min(ceiling, max(64, 1 << max(int(bound) - 1, 0).bit_length()))
+
+
+def eligible_degree_bound_host(n: int, src, dst, d_cap: int,
+                               directed: bool = False) -> int:
+    """B over the input edge list, on the host (every vertex active)."""
+    out_deg = np.bincount(np.asarray(src), minlength=n)[:n]
+    deg = out_deg
+    if directed:
+        deg = deg + np.bincount(np.asarray(dst), minlength=n)[:n]
+    return int(out_deg[deg <= d_cap].sum())
+
+
+def _eligible_degree_bound(src, dst, active, n: int, d_cap: int,
+                           directed: bool):
+    """B over a sentinel-padded device edge list (traced int32)."""
+    valid = src < n
+    out_deg = sops.count_per_segment(src, n + 1, mask=valid)[:n]
+    deg = out_deg
+    if directed:
+        deg = deg + sops.count_per_segment(dst, n + 1, mask=valid)[:n]
+    return jnp.sum(jnp.where(active & (deg <= d_cap), out_deg, 0))
 
 
 @partial(jax.jit, static_argnames=("n", "d_cap", "aug_cap", "directed"))
@@ -176,9 +214,11 @@ def _peel_step(src, dst, w, via, active, level_dev, up_ids, up_w, up_via,
     out-adjacency in ``up_*`` and the in-adjacency in ``up_in``, an
     ``(ids, w, via)`` triple returned last (empty when undirected).
 
-    Returns the updated state plus ``stats`` int32[5] =
-    ``[n_is, n_unique, n_is_edges, mis_rounds, new_size]`` — the one
-    small per-level transfer the host reads. When the IS is empty the
+    Returns the updated state plus ``stats`` int32[6] =
+    ``[n_is, n_unique, n_is_edges, mis_rounds, new_size, bound]`` — the
+    one small per-level transfer the host reads; ``bound`` is the next
+    level's eligible-degree bound, from which the host sizes its
+    augmentation buffer (``level_aug_cap``). When the IS is empty the
     state update is the identity (the host then stops at level ``lvl``
     with the pre-step graph as the core, exactly like the host loop
     that breaks before recording).
@@ -210,7 +250,8 @@ def _peel_step(src, dst, w, via, active, level_dev, up_ids, up_w, up_via,
 
     n_verts = n_verts - n_is
     new_size = n_verts + (n_unique if directed else n_unique // 2)
-    stats = jnp.stack([n_is, n_unique, n_is_edges, rounds, new_size])
+    bound = _eligible_degree_bound(src, dst, active, n, d_cap, directed)
+    stats = jnp.stack([n_is, n_unique, n_is_edges, rounds, new_size, bound])
     return (src, dst, w, via, active, level_dev, up_ids, up_w, up_via,
             rng, n_verts, stats, up_in)
 
@@ -221,8 +262,9 @@ def build_hierarchy_device(n: int, src, dst, w, cfg: IndexConfig,
 
     All state (edge list, active set, level assignment, up-edge matrix)
     stays on device across levels in donated buffers; the host reads one
-    int32[5] stat vector per level to apply the §5.1 stop rule and the
-    capacity checks, then pulls everything once after the loop.
+    int32[6] stat vector per level to apply the §5.1 stop rule and the
+    capacity checks and to size the next level's augmentation buffer,
+    then pulls everything once after the loop.
     ``directed`` takes ``src -> dst`` as arcs and also records the
     in-adjacency up-edges (``Hierarchy.up_in``), in the same pull.
 
@@ -233,7 +275,8 @@ def build_hierarchy_device(n: int, src, dst, w, cfg: IndexConfig,
     """
     m0 = len(src)
     e_cap = cfg.e_cap(m0)
-    aug_cap = cfg.aug_cap(m0)
+    aug_ceiling = cfg.aug_cap(m0)
+    bound = eligible_degree_bound_host(n, src, dst, cfg.d_cap, directed)
     with span("islabel.build.peel.upload"):
         g = gcsr.from_host_edges(src, dst, w, n, e_cap)
 
@@ -251,19 +294,21 @@ def build_hierarchy_device(n: int, src, dst, w, cfg: IndexConfig,
     up_in = up_family() if directed else ()
 
     graph_sizes = [n + (m0 if directed else m0 // 2)]
-    level_sizes, mis_rounds, edges, is_edges = [], [], [], []
+    level_sizes, mis_rounds, edges, is_edges, aug_caps = [], [], [], [], []
     k = 1
     peel_iters = 0
     with hsync.sync_span() as syncs:
         for i in range(1, cfg.k_max + 1):
             peel_iters = i
+            aug_cap = level_aug_cap(bound, aug_ceiling)
+            aug_caps.append(aug_cap)
             with span("islabel.build.peel.level", level=i):
                 *state, stats, up_in = _peel_step(
                     *state, jnp.int32(i), n, cfg.d_cap, aug_cap, directed,
                     up_in)
                 # the single blocking transfer of the level: stop-rule
-                # scalar + overflow flags in one int32[5] read
-                n_is, n_unique, n_is_edges, rounds, new_size = (
+                # scalar, overflow flags and the next bound in one read
+                n_is, n_unique, n_is_edges, rounds, new_size, bound = (
                     int(x) for x in hsync.host_read(stats))
             edges.append(n_unique)
             is_edges.append(n_is_edges)
@@ -307,7 +352,7 @@ def build_hierarchy_device(n: int, src, dst, w, cfg: IndexConfig,
                      core_w=c_w_p[mask], core_via=c_via_p[mask],
                      level_sizes=level_sizes, graph_sizes=graph_sizes,
                      mis_rounds=mis_rounds, host_syncs=loop_syncs,
-                     peel_iters=peel_iters, e_cap=e_cap, aug_cap=aug_cap,
+                     peel_iters=peel_iters, e_cap=e_cap, aug_caps=aug_caps,
                      edges=edges, is_edges=is_edges)
 
 
@@ -390,8 +435,8 @@ def build_hierarchy_host(n: int, src, dst, w, cfg: IndexConfig) -> Hierarchy:
                      core_w=c_w, core_via=c_via, level_sizes=level_sizes,
                      graph_sizes=graph_sizes, mis_rounds=mis_rounds,
                      host_syncs=loop_syncs, peel_iters=peel_iters,
-                     e_cap=e_cap, aug_cap=aug_cap, edges=edges,
-                     is_edges=is_edges)
+                     e_cap=e_cap, aug_caps=[aug_cap] * peel_iters,
+                     edges=edges, is_edges=is_edges)
 
 
 def build_hierarchy(n: int, src, dst, w, cfg: IndexConfig) -> Hierarchy:

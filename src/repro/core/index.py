@@ -107,7 +107,7 @@ def build_stats(n: int, m_input: int, hier: Hierarchy, cfg: IndexConfig,
         peel_edges=sum(hier.edges),
         peel_edge_slots=hier.peel_iters * hier.e_cap,
         peel_aug_edges=sum(hier.is_edges),
-        peel_aug_slots=hier.peel_iters * hier.aug_cap)
+        peel_aug_slots=sum(hier.aug_caps))
 
 
 @dataclasses.dataclass

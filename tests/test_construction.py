@@ -154,9 +154,12 @@ def test_independent_set_matches_serial_greedy(seed):
 
 # ----------------------------------------------------------- determinism
 
+# cave peels eight levels, its augmentation buffer shrinking from the
+# aug_cap_factor ceiling to power-of-two buckets 1024 and 512 on the way
 GRAPHS = [("er", lambda: gen.er_graph(500, 3.0, seed=1)),
           ("rmat", lambda: gen.rmat_graph(9, 8.0, seed=2)),
-          ("grid", lambda: gen.grid_graph(20, seed=3))]
+          ("grid", lambda: gen.grid_graph(20, seed=3)),
+          ("cave", lambda: gen.caveman_graph(20, 10, seed=7))]
 
 
 def _hier_fields(h):
@@ -185,6 +188,24 @@ def test_device_builder_sync_budget():
     h = build_hierarchy_device(n, src, dst, w, IndexConfig())
     assert h.peel_iters >= 1
     assert h.host_syncs <= h.peel_iters
+
+
+def test_level_aug_caps_follow_eligible_degree_bound(peel_levels):
+    """Each level's augmentation buffer is its eligible-degree bound B_i
+    (recounted from the level's own edge list) rounded up to a power of
+    two, at least 64, under the aug_cap_factor ceiling; the IS-incident
+    edges never exceed it, so only the ceiling can overflow."""
+    n, src, dst, w = gen.caveman_graph(20, 10, seed=7)
+    cfg = IndexConfig()
+    ceiling = cfg.aug_cap(len(src))
+    h = build_hierarchy_device(n, src, dst, w, cfg)
+    assert h.peel_iters == len(peel_levels) >= 5
+    assert h.aug_caps == peel_levels.caps() == peel_levels.want(ceiling)
+    assert len(set(h.aug_caps)) >= 3 and min(h.aug_caps) < ceiling
+    for cap, (_, bound), n_is_edges in zip(h.aug_caps, peel_levels,
+                                           h.is_edges):
+        assert n_is_edges <= bound <= cap or cap == ceiling
+        assert n_is_edges <= cap
 
 
 def test_fixed_seed_build_is_deterministic():
@@ -218,7 +239,7 @@ def _brute_label_candidates(idx):
 
 
 @pytest.mark.parametrize("name,mk", GRAPHS)
-def test_build_counters_match_brute_force(name, mk):
+def test_build_counters_match_brute_force(name, mk, peel_levels):
     n, src, dst, w = mk()
     cfg = IndexConfig(l_cap=256, label_chunk=128)
     idx = ISLabelIndex.build(n, src, dst, w, cfg)
@@ -232,7 +253,10 @@ def test_build_counters_match_brute_force(name, mk):
     assert 0 < st.label_candidates <= st.label_slots
     m0 = len(src)
     assert st.peel_edge_slots == st.peel_iters * cfg.e_cap(m0)
-    assert st.peel_aug_slots == st.peel_iters * cfg.aug_cap(m0)
+    # one augmentation buffer per level, sized from its eligible-degree
+    # bound counted here edge by edge
+    assert len(peel_levels) == st.peel_iters
+    assert st.peel_aug_slots == sum(peel_levels.want(cfg.aug_cap(m0)))
     assert 0 < st.peel_aug_edges <= st.peel_aug_slots
     assert 0 < st.peel_edges <= st.peel_edge_slots
     # the host builder reads the same per-level counts one by one
@@ -438,7 +462,7 @@ def test_label_join_has_no_candidate_tile_gather():
 # benchmark run these programs; a change to either is measured there
 # before its digest is updated here.
 PINNED_PROGRAMS = {
-    "peel_step": "197261cc95422e2410e42ac8cc3e2e8d94eb9ed2902594441753d979f20fe4d2",
+    "peel_step": "29fd73048c6a67e4d9364ffc275b160feaeaa53a31ba27ade6e14bcdfaa59d0a",
     "label_chunk_step":
         "5e52cbd7dbd636ea9c5f1e825efc7cd2ef9be658c8477f3d65a2c44829ff40c2",
 }

@@ -174,7 +174,7 @@ def test_undirected_graph_answers_alike_through_both_indexes(seed):
     assert np.isfinite(a).any()
 
 
-def test_directed_build_syncs_and_stats():
+def test_directed_build_syncs_and_stats(peel_levels):
     """One blocking read per peeled level, and BuildStats counted over
     both label families."""
     n, src, dst, w = _rmat_arcs(9, 16, seed=1)
@@ -192,7 +192,10 @@ def test_directed_build_syncs_and_stats():
         <= st.build_seconds
     e_cap, aug_cap = cfg.e_cap(len(src)), cfg.aug_cap(len(src))
     assert st.peel_edge_slots == st.peel_iters * e_cap
-    assert st.peel_aug_slots == st.peel_iters * aug_cap
+    # one augmentation buffer per level, sized from its eligible-degree
+    # bound counted edge by edge
+    assert len(peel_levels) == st.peel_iters
+    assert st.peel_aug_slots == sum(peel_levels.want(aug_cap))
     assert 0 < st.peel_aug_edges <= st.peel_aug_slots
     assert 0 < st.peel_edges <= st.peel_edge_slots
     # two label families over the same levels
@@ -210,6 +213,70 @@ def test_directed_build_syncs_and_stats():
     # the second build of the same shapes compiles nothing
     again = DiISLabelIndex.build(n, src, dst, w, cfg).stats
     assert again.compiles == 0
+
+
+def _cave_arcs(p_drop, seed=7):
+    """Both arcs of every connected-caveman edge, less a random share
+    ``p_drop`` of the arcs: a digraph that peels about ten levels, its
+    eligible-degree bound falling on the way."""
+    from repro.graphs import generators as gen
+    n, src, dst, w = gen.caveman_graph(20, 10, seed=seed)
+    drop = np.random.default_rng(seed).random(len(src)) < p_drop
+    return n, src[~drop], dst[~drop], w[~drop]
+
+
+def test_directed_level_aug_caps_follow_eligible_degree_bound(peel_levels):
+    """Each level's augmentation buffer is its bound B_i, the out-degree
+    sum over active vertices of in + out degree <= d_cap (recounted from
+    the level's own arcs), rounded up to a power of two, at least 64,
+    under the aug_cap_factor ceiling; the IS-incident arcs never exceed
+    it."""
+    from repro.core.hierarchy import build_hierarchy_device
+    n, src, dst, w = _cave_arcs(0.2)
+    cfg = IndexConfig()
+    ceiling = cfg.aug_cap(len(src))
+    h = build_hierarchy_device(n, src, dst, w, cfg, directed=True)
+    assert h.peel_iters == len(peel_levels) >= 5
+    assert h.aug_caps == peel_levels.caps() == peel_levels.want(ceiling)
+    # the ceiling, several buckets and the floor of 64 all occur
+    assert h.aug_caps[0] == ceiling and min(h.aug_caps) == 64
+    assert len(set(h.aug_caps)) >= 4
+    for cap, (_, bound), n_is_edges in zip(h.aug_caps, peel_levels,
+                                           h.is_edges):
+        assert n_is_edges <= bound
+        assert n_is_edges <= cap
+
+
+@pytest.mark.parametrize("name,mk", [
+    ("cave", lambda: _cave_arcs(0.2)),
+    ("rmat", lambda: _rmat_arcs(9, 8, seed=1))])
+def test_directed_bucketed_buffer_bitwise_equals_full_cap(name, mk,
+                                                          monkeypatch):
+    """Shrinking the augmentation buffer to its bucket drops sentinel
+    rows only: levels, up-edges, both label families and the answers
+    equal those of a build whose every level runs the full ceiling."""
+    from repro.core import hierarchy
+    n, src, dst, w = mk()
+    cfg = IndexConfig(l_cap=128, label_chunk=64)
+    got = DiISLabelIndex.build(n, src, dst, w, cfg)
+    monkeypatch.setattr(hierarchy, "level_aug_cap",
+                        lambda bound, ceiling: ceiling)
+    full = DiISLabelIndex.build(n, src, dst, w, cfg)
+    assert full.stats.peel_aug_slots == \
+        full.stats.peel_iters * cfg.aug_cap(len(src))
+    assert got.stats.peel_aug_slots < full.stats.peel_aug_slots
+    assert got.k == full.k
+    np.testing.assert_array_equal(got.level, full.level)
+    for a, b in zip((*got.up_out, *got.up_in, *got.out_lbl, *got.in_lbl,
+                     *got.core_host),
+                    (*full.up_out, *full.up_in, *full.out_lbl,
+                     *full.in_lbl, *full.core_host)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    rng = np.random.default_rng(0)
+    s = rng.integers(0, n, 200).astype(np.int32)
+    t = rng.integers(0, n, 200).astype(np.int32)
+    ans = _exact(got, n, src, dst, w, s, t)
+    assert ans.tobytes() == full.query_host(s, t).tobytes()
 
 
 DIRECTED_SPANS = {
