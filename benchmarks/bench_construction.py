@@ -108,7 +108,7 @@ def gate_rows():
 
 
 def trajectory_point(name: str, spec: str, overrides: dict) -> dict:
-    from repro.data.pipeline import graph_from_spec
+    from repro.graphs.generators import graph_from_spec
     t0 = time.perf_counter()
     n, src, dst, w = graph_from_spec(spec)
     gen_s = time.perf_counter() - t0

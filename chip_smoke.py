@@ -74,7 +74,7 @@ def reference_answers(engine, s, t):
 
 def build(spec: str, l_cap: int):
     from repro.core import ISLabelIndex, IndexConfig
-    from repro.data.pipeline import graph_from_spec
+    from repro.graphs.generators import graph_from_spec
     t0 = time.perf_counter()
     n, src, dst, w = graph_from_spec(spec)
     gen_s = time.perf_counter() - t0

@@ -1,2 +1,2 @@
 from repro.graphs.csr import EdgeList, from_host_edges, degrees, neighbor_matrix
-from repro.graphs import generators, segment_ops, sampler
+from repro.graphs import generators, segment_ops

@@ -1,13 +1,17 @@
-"""Host-side synthetic graph generators (numpy).
+"""Host-side graph sources (numpy): synthetic generators, the SNAP
+edge-list loader, and ``graph_from_spec``.
 
 Real datasets from the paper (BTC, UK-Web, as-Skitter, wiki-Talk,
 web-Google) are not available offline; these generators reproduce their
 *regimes*: sparse power-law (rmat ~ web/social), low-degree semantic
 (sparse ER ~ BTC with avg deg 2.19), meshes (grid), and community
 graphs (caveman). All return (n, src, dst, weight) with both edge
-directions, no self loops, no duplicates, integer-valued float weights.
+directions, no self loops, no duplicates, integer-valued float weights;
+``load_snap_edgelist`` reads a real graph into the same form.
 """
 from __future__ import annotations
+
+from pathlib import Path
 
 import numpy as np
 
@@ -171,3 +175,130 @@ def largest_component_queries(n, src, dst, n_q, seed=0):
     s = rng.choice(big, n_q)
     t = rng.choice(big, n_q)
     return s.astype(np.int32), t.astype(np.int32)
+
+
+# ---------------------------------------------------------------------------
+# Real graphs on disk, and one-string specs naming any source
+# (docs/CONSTRUCTION.md §1).
+
+
+def load_snap_edgelist(path, max_w: int = 1, seed: int = 0,
+                       chunk_lines: int = 2_000_000, relabel: bool = True):
+    """Load a SNAP-format edge list: ``# comment`` header lines, then one
+    ``u v`` (or ``u v w``) pair per line, whitespace-separated.
+
+    The file is parsed in ``chunk_lines``-line blocks (a 10^7-edge file
+    never materializes all its token strings at once); each block is
+    canonicalized to (lo < hi) and deduped on arrival, mirroring the
+    chunked generators. SNAP ids are sparse, so ``relabel`` compacts
+    them to [0, n) (order-preserving). Files without a weight column get
+    unit weights when ``max_w == 1``, else integer weights in
+    [1, max_w] from ``seed`` — same convention as the generators.
+
+    Returns ``(n, src, dst, w)`` with both edge directions.
+    """
+    raw_max = 0
+    cols = None
+    key_chunks, weighted_edges = [], []
+    with open(path) as fh:
+        while True:
+            lines = fh.readlines(chunk_lines * 16)   # ~16 bytes/line hint
+            if not lines:
+                break
+            toks = " ".join(ln for ln in lines if not ln.startswith(("#", "%"))).split()
+            if not toks:
+                continue
+            if cols is None:
+                # column count from the first data line
+                first = next(ln for ln in lines
+                             if not ln.startswith(("#", "%")) and ln.strip())
+                cols = len(first.split())
+                if cols not in (2, 3):
+                    raise ValueError(
+                        f"SNAP edge list needs 2 or 3 columns, got {cols}")
+            arr = np.array(toks, np.float64).reshape(-1, cols)
+            uv = arr[:, :2].astype(np.int64)
+            raw_max = max(raw_max, int(uv.max()) + 1 if len(uv) else 0)
+            if cols == 3:
+                weighted_edges.append((uv, arr[:, 2].astype(np.float32)))
+            else:
+                key_chunks.append(uv)
+    if cols == 3:
+        uv = np.concatenate([e for e, _ in weighted_edges])
+        wt = np.concatenate([w for _, w in weighted_edges])
+        u, v = uv[:, 0], uv[:, 1]
+        if relabel:
+            uniq, inv = np.unique(uv.reshape(-1), return_inverse=True)
+            u, v = inv.reshape(-1, 2).T
+            raw_max = len(uniq)
+        keep = u != v
+        lo = np.minimum(u[keep], v[keep]).astype(np.int64)
+        hi = np.maximum(u[keep], v[keep]).astype(np.int64)
+        # min weight per canonical pair (duplicate rows keep the cheapest)
+        order = np.lexsort((wt[keep], lo * np.int64(raw_max) + hi))
+        key = (lo * np.int64(raw_max) + hi)[order]
+        first = np.concatenate([[True], key[1:] != key[:-1]])
+        pairs = np.stack([key[first] // raw_max, key[first] % raw_max], 1)
+        n = raw_max
+        rng = np.random.default_rng(seed)
+        return _finalize(n, pairs, rng, max_w, weights=wt[keep][order][first])
+    keys = [_pack_pairs(raw_max, c[:, 0], c[:, 1]) for c in key_chunks]
+    keys = np.unique(np.concatenate(keys)) if len(keys) > 1 else keys[0]
+    pairs = _unpack_keys(raw_max, keys)
+    n = raw_max
+    if relabel:
+        uniq, inv = np.unique(pairs.reshape(-1), return_inverse=True)
+        pairs = inv.reshape(-1, 2)
+        n = len(uniq)
+    rng = np.random.default_rng(seed)
+    weights = (np.ones(len(pairs), np.float32) if max_w <= 1
+               else rng.integers(1, max_w + 1, size=len(pairs)).astype(np.float32))
+    return _finalize(n, pairs, rng, max_w, weights=weights)
+
+
+def save_snap_edgelist(path, n, src, dst, w=None, comment: str = ""):
+    """Write the canonical (u < v) edges as a SNAP-format text file —
+    the round-trip partner of ``load_snap_edgelist`` (u v [w] rows)."""
+    src = np.asarray(src)
+    dst = np.asarray(dst)
+    keep = src < dst                       # one row per undirected edge
+    rows = np.stack([src[keep], dst[keep]], 1)
+    path = Path(path)
+    path.parent.mkdir(parents=True, exist_ok=True)
+    with open(path, "w") as fh:
+        fh.write(f"# {comment or 'repro graph'}\n# Nodes: {n} Edges: {keep.sum()}\n")
+        if w is None:
+            np.savetxt(fh, rows, fmt="%d")
+        else:
+            np.savetxt(fh, np.concatenate(
+                [rows, np.asarray(w)[keep][:, None]], 1), fmt="%d %d %g")
+    return path
+
+
+def graph_from_spec(spec: str):
+    """Build ``(n, src, dst, w)`` from a one-string spec.
+
+    Formats: ``er:<n>[:avg_deg]``, ``rmat:<n_pow>[:avg_deg]``,
+    ``pa:<n>[:m_per]``, ``grid:<side>``, ``snap:<path>`` — each with an
+    optional trailing ``@seed`` (default 0).
+    """
+    spec, _, seed_s = spec.partition("@")
+    seed = int(seed_s) if seed_s else 0
+    kind, *args = spec.split(":")
+    if kind == "er":
+        n = int(args[0])
+        deg = float(args[1]) if len(args) > 1 else 3.0
+        return er_graph(n, deg, seed=seed)
+    if kind == "rmat":
+        p = int(args[0])
+        deg = float(args[1]) if len(args) > 1 else 8.0
+        return rmat_graph(p, deg, seed=seed)
+    if kind == "pa":
+        n = int(args[0])
+        m_per = int(args[1]) if len(args) > 1 else 2
+        return pa_graph(n, m_per, seed=seed)
+    if kind == "grid":
+        return grid_graph(int(args[0]), seed=seed)
+    if kind == "snap":
+        return load_snap_edgelist(":".join(args), seed=seed)
+    raise ValueError(f"unknown graph spec kind: {kind!r} (in {spec!r})")

@@ -1,17 +1,14 @@
 """Segment reductions — the scatter/gather substrate for everything graph.
 
-JAX has no CSR/CSC sparse and no EmbeddingBag: all message passing in
-this framework (GNNs, IS-LABEL construction, wavefront relaxation,
-embedding bags) is expressed as ``gather -> elementwise -> segment_*``
-over an edge index. These wrappers pin ``num_segments`` static and fix
-the fill values for empty segments.
+JAX has no CSR/CSC sparse: the graph passes in this repo (IS-LABEL
+construction, wavefront relaxation) are expressed as ``gather ->
+elementwise -> segment_*`` over an edge index. These wrappers pin
+``num_segments`` static and fix the fill values for empty segments.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-
-INF = jnp.inf
 
 
 def segment_sum(data, segment_ids, num_segments: int):
@@ -25,26 +22,6 @@ def segment_min(data, segment_ids, num_segments: int):
 
 def segment_max(data, segment_ids, num_segments: int):
     return jax.ops.segment_max(data, segment_ids, num_segments=num_segments)
-
-
-def segment_mean(data, segment_ids, num_segments: int):
-    tot = segment_sum(data, segment_ids, num_segments)
-    cnt = segment_sum(jnp.ones(data.shape[:1], data.dtype), segment_ids, num_segments)
-    return tot / jnp.maximum(cnt, 1.0).reshape((-1,) + (1,) * (data.ndim - 1))
-
-
-def segment_softmax(logits, segment_ids, num_segments: int):
-    """Numerically-stable softmax within segments (edge-softmax for GAT)."""
-    seg_max = segment_max(logits, segment_ids, num_segments)
-    shifted = logits - jnp.where(jnp.isfinite(seg_max), seg_max, 0.0)[segment_ids]
-    ex = jnp.exp(shifted)
-    denom = segment_sum(ex, segment_ids, num_segments)
-    return ex / jnp.maximum(denom[segment_ids], 1e-30)
-
-
-def scatter_min(target, idx, vals):
-    """target[idx] = min(target[idx], vals) with duplicate idx allowed."""
-    return target.at[idx].min(vals)
 
 
 def segment_argmin_take(data, payload, segment_ids, num_segments: int):

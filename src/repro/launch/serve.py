@@ -1,7 +1,5 @@
-"""Serving launcher — two serving modes:
+"""Serving launcher for the distance service — four modes:
 
-* ``--mode lm``: prefill + decode loop for a (smoke) LM config: batched
-  requests, KV-cache reuse, tokens/s report.
 * ``--mode distance``: the paper's workload, served through the
   ``repro.serve`` subsystem (docs/SERVING.md): build or load an
   IS-LABEL index, register it, replay a scenario trace from the load
@@ -72,8 +70,6 @@ import argparse
 import json
 import time
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 
 
@@ -146,30 +142,6 @@ class _ObsSession:
         self.log.log("finish", mode=self.mode, failures=failures)
         self.log.close()
         return failures
-
-
-def serve_lm(args):
-    from repro.configs import registry
-    from repro.launch.train import smoke_spec
-    from repro.models.transformer import decode_step, init_lm, prefill
-    spec = smoke_spec(registry.get_spec(args.arch))
-    cfg = spec.model_cfg
-    params = init_lm(jax.random.PRNGKey(0), cfg)[0]
-    b, prompt_len, gen_len = args.batch, 16, args.gen_len
-    toks = np.random.default_rng(0).integers(
-        0, cfg.vocab, (b, prompt_len)).astype(np.int32)
-    pf = jax.jit(lambda p, t: prefill(p, cfg, t, prompt_len + gen_len))
-    dc = jax.jit(lambda p, c, t: decode_step(p, cfg, c, t))
-    t0 = time.time()
-    logits, cache = pf(params, toks)
-    out = [jnp.argmax(logits[:, -1:], -1).astype(jnp.int32)]
-    for _ in range(gen_len - 1):
-        logits, cache = dc(params, cache, out[-1])
-        out.append(jnp.argmax(logits, -1).astype(jnp.int32))
-    total = b * gen_len
-    dt = time.time() - t0
-    print(f"[serve-lm {spec.arch_id}] {total} tokens in {dt:.2f}s "
-          f"({total / dt:.1f} tok/s incl. compile)")
 
 
 def _build_graph(args):
@@ -623,12 +595,8 @@ def serve_http(args) -> int:
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--mode", choices=["lm", "distance", "path", "mutate",
-                                       "http"],
+    ap.add_argument("--mode", choices=["distance", "path", "mutate", "http"],
                     default="distance")
-    ap.add_argument("--arch", default="granite-8b")
-    ap.add_argument("--batch", type=int, default=256)
-    ap.add_argument("--gen-len", type=int, default=32)
     # -- distance serving (thin CLI over repro.serve) ----------------------
     ap.add_argument("--graph", choices=["rmat", "er", "grid"], default="rmat")
     ap.add_argument("--n", type=int, default=4096)
@@ -707,9 +675,7 @@ def main():
     args = ap.parse_args()
     from repro.launch.compile_cache import configure_compile_cache
     configure_compile_cache()
-    if args.mode == "lm":
-        serve_lm(args)
-    elif args.mode == "mutate":
+    if args.mode == "mutate":
         raise SystemExit(serve_mutate(args))
     elif args.mode == "http":
         raise SystemExit(serve_http(args))

@@ -4,13 +4,13 @@ histograms, all supporting labeled series.
 One registry instance (the module-level ``REGISTRY`` by default) is
 shared by every component of the serving stack — ``DistanceServer``,
 ``VersionManager``, ``ShardedQueryEngine``, ``PathEngine``,
-``repro.fault`` — so a single ``snapshot()`` (or ``launch/serve.py
+``repro.serve.stragglers`` — so a single ``snapshot()`` (or ``launch/serve.py
 --metrics-out``) captures the whole process. ``ServeMetrics`` keeps its
 historical per-server snapshot shape but is a *view* over series held
 here (docs/OBSERVABILITY.md).
 
 Naming scheme: dotted ``<component>.<metric>`` names (``serve.served``,
-``versions.swaps``, ``fault.retries``); unit suffixes where the value is
+``versions.swaps``, ``fault.straggler_flags``); unit suffixes where the value is
 not a plain count (``_seconds``, ``_bytes``, ``_ratio``). Series within
 a metric are keyed by their sorted ``(label, value)`` items, so
 ``counter.inc(server="g", lane="mu")`` and a later

@@ -612,10 +612,10 @@ class DistanceServer:
                 "core_cap": self.versions.family.core_cap,
                 "edge_cap": self.versions.family.edge_cap,
             }),
-            # process-wide registry sections: fault-tolerance counters
-            # (repro.fault reports straggler/retry stats here — satellite
-            # visibility through the serving surface) and the compile /
-            # memory observability gauges (docs/OBSERVABILITY.md)
+            # process-wide registry sections: straggler health
+            # (repro.serve.stragglers reports its fault.* series here)
+            # and the compile / memory observability gauges
+            # (docs/OBSERVABILITY.md)
             "fault": self.registry.section("fault.") or None,
             "obs": self.registry.section("obs.") or None,
             **self.metrics.snapshot(),

@@ -11,7 +11,7 @@ answers are bitwise identical regardless of which replica serves them —
 replication changes *timing*, never *values*).
 
 Health: after every pump, each replica's new per-batch execution times
-feed the ``repro.fault`` straggler machinery — one ``StragglerMonitor``
+feed the ``repro.serve.stragglers`` machinery — one ``StragglerMonitor``
 per replica under a ``HostTimingAggregator`` fleet view. The two
 detectors are complementary: the per-replica EMA flags *degradation
 onset* (a replica that was fast and got slow), the fleet-median
@@ -45,9 +45,9 @@ from __future__ import annotations
 
 import numpy as np
 
-from repro.fault.stragglers import HostTimingAggregator, StragglerMonitor
 from repro.obs.registry import REGISTRY
 from repro.serve.engine import DistanceServer
+from repro.serve.stragglers import HostTimingAggregator, StragglerMonitor
 
 __all__ = ["ReplicaSet"]
 

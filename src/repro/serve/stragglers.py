@@ -1,20 +1,15 @@
-"""Straggler detection & mitigation hooks.
+"""Straggler detection: per-host step-time EMAs and a fleet-median view.
 
-On a real fleet each host reports step wall-time; the controller compares
-against the EMA and flags hosts persistently above ``threshold`` x the
-fleet median (SPMD steps are synchronous, so one slow host gates all).
-Mitigations wired here: (1) alert hook, (2) data re-balancing hint
-(shrink the flagged host's shard of the next data window), (3) eviction
-recommendation after ``evict_after`` consecutive flags — the elastic
-restart path (checkpoint + re-mesh) then removes the host.
-
-Single-process builds exercise the same logic with simulated timings
-(tests/test_fault.py).
+Each host (a serving replica, in ``ReplicaSet``) reports step wall-time;
+``StragglerMonitor`` flags steps above ``threshold`` x its own EMA and
+recommends eviction after ``evict_after`` consecutive flags, and
+``HostTimingAggregator`` flags hosts whose EMA sits above ``threshold``
+x the fleet median. Tests drive both with scripted timings
+(tests/test_stragglers.py).
 
 Both classes report into the process metric registry
 (``repro.obs.REGISTRY``, ``fault.*`` series), which the serving stack
-surfaces through ``DistanceServer.stats()["fault"]`` — one place to
-read training-side straggler state next to the serving metrics.
+surfaces through ``DistanceServer.stats()["fault"]``.
 """
 from __future__ import annotations
 

@@ -9,9 +9,9 @@
 No device holds the full label table: shard p's block carries its
 ancestor partition plus the replicated top hierarchy levels
 (``partition.py``), stacked [P, n+1, cap_s] and laid over a 1-D
-``jax.sharding.Mesh`` shard axis via the ``"graph_index"`` logical-axis
-rules in ``repro.distributed.sharding`` (label_shard → mesh shard;
-vertex rows, levels, and the core graph replicated). Queries run
+``jax.sharding.Mesh`` shard axis via the logical-axis rules
+``GRAPH_INDEX_RULES`` below (label_shard → mesh shard; vertex rows,
+levels, and the core graph replicated). Queries run
 through ``ShardedQueryEngine`` (shard_map + one pmin per batch).
 """
 from __future__ import annotations
@@ -23,15 +23,44 @@ from pathlib import Path
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import Mesh, NamedSharding
+from jax.sharding import PartitionSpec as P
 
 from repro.core.config import BuildStats, IndexConfig
-from repro.distributed.sharding import FAMILY_RULES, tree_shardings
 from repro.shard.partition import (LabelBlocks, assign_shards,
                                    partition_labels)
 from repro.shard.query import ShardedQueryEngine
 
-# logical axes of every placed leaf, resolved through the family rules
+# Logical axis -> mesh axis. Label blocks are stacked [P, n+1, cap_s]
+# with the leading label-partition axis laid over the mesh's "shard"
+# axis; per-vertex rows ("vertex"), label slots, hierarchy levels, and
+# the whole core graph stay replicated — the core search runs
+# shard-locally (top levels are replicated into every label block) and
+# only the Equation-1 partial minima cross shards (one collective per
+# batch; docs/SHARDING.md).
+GRAPH_INDEX_RULES = {
+    "label_shard": "shard",   # one label partition per mesh slice
+    "vertex": None,           # [n+1] rows: every shard sees all vertices
+    "label_slot": None,       # padded per-shard label columns
+    "level": None,            # hierarchy levels: replicated
+    "core_vertex": None,      # core_pos / seed columns: replicated
+    "core_edge": None,        # G_k COO arrays: replicated
+}
+
+
+def spec_for_axes(axes: tuple, rules: dict) -> P:
+    return P(*(rules.get(ax) for ax in axes))
+
+
+def tree_shardings(axes_tree, rules: dict, mesh):
+    """Map a logical-axes tree to NamedShardings."""
+    def one(ax):
+        return NamedSharding(mesh, spec_for_axes(ax, rules))
+    return jax.tree.map(one, axes_tree,
+                        is_leaf=lambda x: isinstance(x, tuple))
+
+
+# logical axes of every placed leaf, resolved through GRAPH_INDEX_RULES
 _AXES_TREE = {
     "lbl_ids": ("label_shard", "vertex", "label_slot"),
     "lbl_d": ("label_shard", "vertex", "label_slot"),
@@ -139,8 +168,7 @@ class ShardedIndex:
         if mesh.shape[axis] != num_shards:
             raise ValueError(f"mesh axis {axis!r} has size "
                              f"{mesh.shape[axis]}, need {num_shards}")
-        shardings = tree_shardings(_AXES_TREE, FAMILY_RULES["graph_index"],
-                                   mesh)
+        shardings = tree_shardings(_AXES_TREE, GRAPH_INDEX_RULES, mesh)
         host = {
             "lbl_ids": blocks.ids, "lbl_d": blocks.d,
             "core_pos": core_pos,

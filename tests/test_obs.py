@@ -401,35 +401,8 @@ def test_traced_serve_full_request_coverage(index, tmp_path):
 
 
 # ------------------------------------------------- fault registry wiring
-def test_fault_events_surface_in_registry_and_server_stats(index,
-                                                           tmp_path):
-    from repro.fault import (FaultTolerantRunner, HostTimingAggregator,
-                             RunnerConfig)
-    ev = REGISTRY.counter("fault.events")
-    fail_before = ev.value(kind="step_failure")
-    rb_before = ev.value(kind="rollback")
-    steps_before = REGISTRY.counter("fault.steps").total()
-
-    fail_plan = {2: 1}                        # step 2 raises once
-
-    def make_batch(step):
-        return float(step + 1)
-
-    def step_fn(state, batch):
-        step = int(batch) - 1
-        if fail_plan.get(step, 0) > 0:
-            fail_plan[step] -= 1
-            raise RuntimeError("injected")
-        return ({"x": state["x"] + batch}, {"loss": np.float32(1.0)})
-
-    cfg = RunnerConfig(ckpt_dir=str(tmp_path), ckpt_every=2,
-                       handle_sigterm=False)
-    runner = FaultTolerantRunner(step_fn, {"x": np.float64(0.0)},
-                                 make_batch, cfg)
-    runner.run(4)
-    assert ev.value(kind="step_failure") - fail_before == 1
-    assert ev.value(kind="rollback") - rb_before == 1
-    assert REGISTRY.counter("fault.steps").total() - steps_before >= 4
+def test_fault_events_surface_in_registry_and_server_stats(index):
+    from repro.serve.stragglers import HostTimingAggregator
 
     agg = HostTimingAggregator(threshold=1.3)
     for _ in range(4):
@@ -446,7 +419,6 @@ def test_fault_events_surface_in_registry_and_server_stats(index,
     # ...and the serving stack surfaces the same section in stats()
     srv = DistanceServer(index, buckets=(8,), max_wait_ms=1.0)
     fault = srv.stats()["fault"]
-    assert any(k.startswith("fault.events") for k in fault)
     assert any(k.startswith("fault.step_seconds_ema") for k in fault)
 
 

@@ -4,12 +4,13 @@ Covers: shard assignment invariants, partition round-trip (reassembled
 labels == original), bitwise sharded-vs-unsharded query equality across
 backends × shard counts {1, 2, 4} × strategies, the single-collective
 guarantee, sharded save→load→serve, zero-compiles-after-warmup on the
-sharded lane, and a mixed sharded/unsharded registry.
+sharded lane, a mixed sharded/unsharded registry, and the unsharded
+engine fed batch-sharded queries on an 8-device mesh.
 
 Multi-shard cases need >1 device: they run in subprocesses under
-``--xla_force_host_platform_device_count=4`` (this process must keep
-seeing the real 1-CPU world, per the dry-run isolation rule); the
-P=1 paths run in-process on the real device.
+``--xla_force_host_platform_device_count=4`` (8 for the batch-sharded
+case; this process must keep seeing the real 1-CPU world); the P=1
+paths run in-process on the real device.
 """
 from __future__ import annotations
 
@@ -228,4 +229,36 @@ def test_sharded_save_load_serve_and_zero_compiles():
         assert np.array_equal(a, b)
         print("ok")
     """)
+    assert "ok" in out
+
+
+# ---------------------------------------- batch-sharded unsharded engine
+def test_islabel_query_sharded_matches_local():
+    """The unsharded query engine, fed queries batch-sharded over an
+    8-device mesh, returns the same distances as on one device."""
+    out = run_with_devices("""
+        import jax, jax.numpy as jnp, numpy as np
+        from repro.core import ISLabelIndex, IndexConfig
+        from repro.graphs import generators as gen
+        n, src, dst, w = gen.er_graph(400, 3.0, seed=5)
+        idx = ISLabelIndex.build(n, src, dst, w,
+                                 IndexConfig(l_cap=128, label_chunk=128))
+        r = np.random.default_rng(0)
+        s = r.integers(0, n, 64).astype(np.int32)
+        t = r.integers(0, n, 64).astype(np.int32)
+        want = np.asarray(idx.query(s, t))
+        # shard the queries across 8 devices; Auto axes let the
+        # engine's single-device label gathers propagate the batch
+        # sharding (Explicit axes would demand an out_sharding per gather)
+        from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
+        mesh = jax.make_mesh((4, 2), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
+        sq = jax.device_put(jnp.asarray(s), NamedSharding(mesh, P("data")))
+        tq = jax.device_put(jnp.asarray(t), NamedSharding(mesh, P("data")))
+        got = np.asarray(idx.engine.query(sq, tq))
+        fin = np.isfinite(want)
+        assert (np.isfinite(got) == fin).all()
+        np.testing.assert_allclose(got[fin], want[fin], rtol=1e-5)
+        print("ok")
+    """, n_dev=8)
     assert "ok" in out
